@@ -214,6 +214,10 @@ def run_fusion(n: Optional[int] = None, check_n: int = 16, repeat: int = 5,
 def main():
     import argparse
 
+    from repro.core.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--fusion", action="store_true",
                     help="run the fused-vs-unfused comparison only")

@@ -63,6 +63,9 @@ def bench_kernels():
 
 
 def main() -> None:
+    from repro.core.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
     t0 = time.perf_counter()
     _section("polybench (paper Table 4 / Fig 8)")
     from . import polybench

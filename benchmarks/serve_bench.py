@@ -141,8 +141,6 @@ def run_stap(smoke: bool = False) -> List[Dict]:
 # ---------------------------------------------------------------------------
 
 def run_lm(smoke: bool = False) -> List[Dict]:
-    import jax
-
     from repro.configs import get_smoke_config
     from repro.distrib import ClusterRuntime
     from repro.models import transformer as T
@@ -154,35 +152,40 @@ def run_lm(smoke: bool = False) -> List[Dict]:
     n_slots, max_seq, workers = 2, 64, 1
     rate_rps = 20.0
 
-    cfg = get_smoke_config("stablelm_3b")
-    params, _ = T.init_params(cfg, jax.random.key(0))
-    rng = np.random.default_rng(3)
-    prompts = [rng.integers(0, cfg.vocab, int(rng.integers(4, 12)))
-               for _ in range(requests)]
-
-    # single-process reference (and its own telemetry row)
-    ref_eng = ServeEngine(params, cfg, n_slots=n_slots, max_seq=max_seq)
-    t0 = time.perf_counter()
-    for i, p in enumerate(prompts):
-        ref_eng.add_request(Request(f"req-{i}", p,
-                                    max_tokens=max_tokens))
-    ref_done = ref_eng.run_until_done()
-    ref_wall = time.perf_counter() - t0
-    ref = {r.request_id: list(r.generated) for r in ref_done}
-    ref_tel = ref_eng.telemetry()
-    rows: List[Dict] = [{
-        "flagship": "lm_decode", "mode": "single_process",
-        "workers": 0, "requests": requests, "measured": True,
-        "tokens_generated": ref_tel["tokens_generated"],
-        "throughput_tok_s": round(
-            ref_tel["tokens_generated"] / ref_wall, 2),
-        "ttft_ms": ref_tel["latency"]["ttft_ms"],
-        "tpot_ms": ref_tel["latency"]["tpot_ms"],
-        "e2e_ms": ref_tel["latency"]["e2e_ms"],
-    }]
-
+    # spawn the decode worker before this process touches jax: on an
+    # accelerator host the first process to initialise jax holds the chip
     rt = ClusterRuntime(workers=workers, start_method="spawn")
     try:
+        import jax
+
+        cfg = get_smoke_config("stablelm_3b")
+        params, _ = T.init_params(cfg, jax.random.key(0))
+        rng = np.random.default_rng(3)
+        prompts = [rng.integers(0, cfg.vocab, int(rng.integers(4, 12)))
+                   for _ in range(requests)]
+
+        # single-process reference (and its own telemetry row)
+        ref_eng = ServeEngine(params, cfg, n_slots=n_slots,
+                              max_seq=max_seq)
+        t0 = time.perf_counter()
+        for i, p in enumerate(prompts):
+            ref_eng.add_request(Request(f"req-{i}", p,
+                                        max_tokens=max_tokens))
+        ref_done = ref_eng.run_until_done()
+        ref_wall = time.perf_counter() - t0
+        ref = {r.request_id: list(r.generated) for r in ref_done}
+        ref_tel = ref_eng.telemetry()
+        rows: List[Dict] = [{
+            "flagship": "lm_decode", "mode": "single_process",
+            "workers": 0, "requests": requests, "measured": True,
+            "tokens_generated": ref_tel["tokens_generated"],
+            "throughput_tok_s": round(
+                ref_tel["tokens_generated"] / ref_wall, 2),
+            "ttft_ms": ref_tel["latency"]["ttft_ms"],
+            "tpot_ms": ref_tel["latency"]["tpot_ms"],
+            "e2e_ms": ref_tel["latency"]["e2e_ms"],
+        }]
+
         eng = ClusterLMEngine(rt, params, cfg, n_slots=n_slots,
                               max_seq=max_seq, trim_every=16)
         # warm the worker's jit cache off the measured clock (the
@@ -233,6 +236,10 @@ def run_lm(smoke: bool = False) -> List[Dict]:
 
 def main() -> None:
     import sys
+
+    from repro.core.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
 
     smoke = "--smoke" in sys.argv
     rows: List[Dict] = []

@@ -701,6 +701,10 @@ def run_chaos(smoke: bool = False,
 def main():
     import sys
 
+    from repro.core.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
+
     if "--hetero" in sys.argv:
         run_hetero(smoke="--smoke" in sys.argv)
     elif "--pallas" in sys.argv:

@@ -65,6 +65,9 @@ def bench(repeat: int = 3):
 
 
 if __name__ == "__main__":
+    from repro.core.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--repeat", type=int, default=3,
                     help="warm-compile repetitions (best-of)")

@@ -34,12 +34,14 @@ the ``pallas`` backend below, or CuPy/Triton later — is one
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from typing import (Any, Callable, Collection, Dict, FrozenSet, List,
+                    Optional)
 
 __all__ = [
     "Backend", "BackendUnavailable", "register", "unregister", "get",
     "is_registered", "names", "twin_backends", "twin_names",
-    "degradation_chain", "cache_token",
+    "degradation_chain", "cache_token", "feasible", "on_chip",
+    "device_precision",
 ]
 
 
@@ -85,6 +87,9 @@ class Backend:
     effective_gflops: Optional[Callable[[Any], float]] = None
     # (profile) -> can this worker run the twin at all
     feasible: Optional[Callable[[Any], bool]] = None
+    # array dtypes this backend's bodies run on a real accelerator chip
+    # (None: any); see :func:`feasible`
+    chip_dtypes: Optional[FrozenSet[str]] = None
 
     @property
     def attr(self) -> str:
@@ -155,6 +160,52 @@ def degradation_chain(name: str) -> List[str]:
     if "np" not in chain and name != "np":
         chain.append("np")
     return chain
+
+
+# Matmul precision the device bodies (whole-kernel jnp variants and
+# every accelerator twin) compute at. The TPU's default runs an f32
+# matmul as one bf16 pass, about three significant digits, which the np
+# reference does not match; "highest" makes it f32-accurate. CPU
+# backends compute at full precision either way.
+DEVICE_MATMUL_PRECISION = "highest"
+
+
+def device_precision():
+    """Context in which device bodies are traced and run."""
+    import jax
+
+    return jax.default_matmul_precision(DEVICE_MATMUL_PRECISION)
+
+
+def _dtypes_run_on(bk: Backend, platform: str,
+                   dtypes: Collection[str]) -> bool:
+    """Do ``bk``'s bodies run arrays of ``dtypes`` on ``platform`` (a
+    profile's ``gpu_kind`` or a jax backend name)? Off a real chip (no
+    device, jax's CPU, or jax-CPU posing as a device in tests) every
+    dtype runs; on one only the backend's ``chip_dtypes``."""
+    return (bk.chip_dtypes is None or platform in ("", "sim", "cpu")
+            or set(dtypes) <= bk.chip_dtypes)
+
+
+def feasible(bk: Backend, profile, dtypes: Collection[str] = ()) -> bool:
+    """Can ``profile``'s worker run ``bk``'s body for a unit whose
+    captured arrays hold ``dtypes``? On a real chip a dtype outside the
+    backend's ``chip_dtypes`` keeps the unit off it: the TPU compiler
+    aborts the whole process on a c128 matmul (no exception to catch),
+    and Mosaic has no f64. Lowering those dtypes is a separate policy;
+    here such units simply stay on np."""
+    if bk.feasible is not None and not bk.feasible(profile):
+        return False
+    return _dtypes_run_on(bk, getattr(profile, "gpu_kind", ""), dtypes)
+
+
+def runs_here(bk: Backend, dtypes: Collection[str] = ()) -> bool:
+    """Can this process run ``bk``'s body itself (the in-process
+    whole-kernel variant) on arrays of ``dtypes``? The dtype rule of
+    :func:`feasible`, applied to the platform jax gives this process."""
+    import jax
+
+    return _dtypes_run_on(bk, jax.default_backend(), dtypes)
 
 
 def cache_token(accel_ok: bool) -> str:
@@ -233,6 +284,17 @@ def _pallas_chunk_seconds(flops: float, nbytes: float, profile) -> float:
     overhead = PALLAS_CHUNK_OVERHEAD_S if real else 0.0
     return max(flops / (rate * 1e9),
                nbytes / (xfer_gbs * 1e9)) + overhead
+
+
+# every dtype XLA runs on the chip except complex128 (f64 is emulated:
+# slow, but correct)
+_JNP_CHIP_DTYPES = frozenset({
+    "bool", "int8", "int16", "int32", "int64", "uint8", "uint16",
+    "uint32", "uint64", "float16", "bfloat16", "float32", "float64",
+    "complex64"})
+
+# the in-repo Pallas kernels compile for f32 and bf16 operands
+_PALLAS_CHIP_DTYPES = frozenset({"float32", "bfloat16"})
 
 
 def _accel_feasible(profile) -> bool:
@@ -330,6 +392,7 @@ register(Backend(
     chunk_seconds=_jnp_chunk_seconds,
     effective_gflops=_gpu_effective_gflops,
     feasible=_accel_feasible,
+    chip_dtypes=_JNP_CHIP_DTYPES,
 ))
 
 register(Backend(
@@ -346,4 +409,5 @@ register(Backend(
     effective_gflops=lambda p: _gpu_effective_gflops(p)
     * PALLAS_FUSION_SPEEDUP,
     feasible=_accel_feasible,
+    chip_dtypes=_PALLAS_CHIP_DTYPES,
 ))

@@ -250,27 +250,25 @@ def chunk_backend_seconds(flops: float, nbytes: float, profile,
     return bk.chunk_seconds(flops, nbytes, profile)
 
 
-def _feasible(bk, profile) -> bool:
-    return bk.feasible is None or bk.feasible(profile)
-
-
 def pick_chunk_backend(flops: float, nbytes: float, profile,
                        allow_jnp: bool = True,
-                       candidates: Optional[Tuple[str, ...]] = None) -> str:
+                       candidates: Optional[Tuple[str, ...]] = None,
+                       dtypes: Tuple[str, ...] = ()) -> str:
     """Choose the cheapest body backend for one worker's chunk.
 
     ``candidates`` are the twin backends whose bodies actually exist for
     the unit (None keeps the legacy jnp-or-np contract). Only workers
     the backend declares itself feasible on (e.g. a real or simulated
-    GPU) are priced against it; a zero FLOP estimate (direct calls that
-    bypassed the dispatcher) degrades to capability tags — the
+    GPU) for the unit's array ``dtypes`` are priced against it; a zero
+    FLOP estimate (direct calls that bypassed the dispatcher) degrades
+    to capability tags — the
     highest-priority feasible candidate wins. Ties price to np: a twin
     must be *strictly* cheaper to leave the always-correct body."""
     if candidates is None:
         candidates = ("jnp",) if allow_jnp else ()
     live = [backends.get(c) for c in candidates
             if backends.is_registered(c)]
-    live = [bk for bk in live if _feasible(bk, profile)]
+    live = [bk for bk in live if backends.feasible(bk, profile, dtypes)]
     if not live:
         return "np"
     live.sort(key=lambda bk: -bk.priority)
@@ -287,13 +285,13 @@ def pick_chunk_backend(flops: float, nbytes: float, profile,
 
 def unit_backend_table(flops_per_worker: float, nbytes_per_worker: float,
                        profiles: Iterable, allow_jnp: bool = True,
-                       candidates: Optional[Tuple[str, ...]] = None
-                       ) -> List[str]:
+                       candidates: Optional[Tuple[str, ...]] = None,
+                       dtypes: Tuple[str, ...] = ()) -> List[str]:
     """Backend choice per worker profile for one pfor unit (in profile
     order) — the row of the (unit, backend, worker) pricing table the
     sharder consumes."""
     return [pick_chunk_backend(flops_per_worker, nbytes_per_worker, p,
-                               allow_jnp, candidates)
+                               allow_jnp, candidates, dtypes)
             for p in profiles]
 
 

@@ -25,7 +25,7 @@ import numpy as np
 from repro import obs
 from repro.obs.metrics import Counter
 
-from . import cost
+from . import backends, cost
 from .codegen import GeneratedVariant
 from .schedule import Schedule
 from .types import (TypeInfo, matches, nested_list_shape,
@@ -217,7 +217,8 @@ class CompiledKernel:
             return self.variants["original"], rec
         flops = self.estimate_flops(bound)
         profitable = cost.accel_profitable(flops, self.accel_threshold)
-        if profitable and "jnp" in self.variants:
+        if (profitable and "jnp" in self.variants
+                and self._jnp_runs_here(bound)):
             rec = DispatchRecord("jnp", True, flops, True)
             return self.variants["jnp"], rec
         if "np" in self.variants:
@@ -225,6 +226,15 @@ class CompiledKernel:
             return self.variants["np"], rec
         rec = DispatchRecord("original", True, flops, profitable)
         return self.variants["original"], rec
+
+    @staticmethod
+    def _jnp_runs_here(bound: Dict[str, Any]) -> bool:
+        """Can this process run the whole-kernel jnp variant on these
+        arrays? On a chip only for the dtypes the jnp backend runs there
+        (:func:`backends.runs_here`)."""
+        return backends.runs_here(backends.get("jnp"), {
+            str(v.dtype) for v in bound.values()
+            if isinstance(v, np.ndarray)})
 
     def __call__(self, *args, **kwargs):
         bound = self._bind(args, kwargs)
@@ -365,7 +375,11 @@ class CompiledKernel:
         args = [bound[n] for n in names]
         if variant.name == "original":
             return variant.fn(*args)
-        result = variant.fn(*args)
+        if variant.name == "jnp":
+            with backends.device_precision():
+                result = variant.fn(*args)
+        else:
+            result = variant.fn(*args)
         gen = variant.generated
         if gen is not None and gen.returns_written and result is not None:
             outs = result if isinstance(result, tuple) else (result,)

@@ -34,8 +34,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["pfor_jit", "remember", "take_stats", "stats", "reset",
-           "WIRE_STAT_KEYS"]
+__all__ = ["pfor_jit", "vmapped", "remember", "take_stats", "stats",
+           "reset", "WIRE_STAT_KEYS"]
 
 # Every counter key a worker may piggyback on a chunk "done" message —
 # this module's jit/residency counters plus the pallas runtime's call
@@ -193,6 +193,18 @@ def _device_array(jax, jnp, host, sliced: bool, pad_rows: int):
     return _stage(jax, jnp, raw, pad_rows)
 
 
+def vmapped(iter_fn):
+    """The program :func:`pfor_jit` compiles for one iteration function:
+    ``iter_fn(g, offs, *arrays)`` vmapped over a vector of iteration
+    indices ``g`` and jitted, called as ``(idx, offs, *arrays)``."""
+    jax = _jax()
+
+    def _run(idx, offs, *arrs):
+        return jax.vmap(lambda g: iter_fn(g, offs, *arrs))(idx)
+
+    return jax.jit(_run)
+
+
 def pfor_jit(iter_fn, lo: int, hi: int, arrays: Sequence[Any],
              write_pos: Sequence[int]) -> bool:
     """Run ``iter_fn(g, offs, *arrays)`` for every g in [lo, hi) as one
@@ -261,13 +273,9 @@ def pfor_jit(iter_fn, lo: int, hi: int, arrays: Sequence[Any],
     offs_arr = jnp.asarray(np.asarray(offs, dtype=np.int64))
 
     if fn is _UNSET:
-        captured = iter_fn  # pin: later cache hits reuse this closure,
-        # which is semantically identical (same code + same baked cells)
-
-        def _run(idx, offs, *arrs):
-            return jax.vmap(lambda g: captured(g, offs, *arrs))(idx)
-
-        fn = jax.jit(_run)
+        # later cache hits reuse this closure, which is semantically
+        # identical (same code + same baked cells)
+        fn = vmapped(iter_fn)
         t0 = time.perf_counter()
         try:
             out = jax.block_until_ready(fn(idx, offs_arr, *devs))

@@ -7,7 +7,9 @@ use — ``submit`` / ``get`` / ``wait`` / ``stats`` — plus the
 runtime crosses process boundaries:
 
   * workers are real OS processes (``multiprocessing`` transport, fork
-    or spawn), each reporting a measured :class:`DeviceProfile`;
+    or spawn), each reporting a measured :class:`DeviceProfile`; the
+    head decides which of them own an accelerator chip, one chip each
+    (``device_workers``), and pins every other process to the CPU;
   * placement goes through :class:`PlacementScheduler` — capability +
     data-locality − load — and pfor chunks are sized proportional to
     each worker's measured GFLOP/s (heterogeneous fleets get uneven,
@@ -35,6 +37,7 @@ import logging
 import multiprocessing as mp
 import os
 import signal
+import socket
 import threading
 import time
 from dataclasses import dataclass, field
@@ -48,7 +51,8 @@ from repro.core import backends as backends_mod
 
 from .accel import WIRE_STAT_KEYS as accel_wire_stat_keys
 from .chaos import ChaosPlan, ChaosWire
-from .device import DeviceProfile, measure_profile, sim_gpu_for
+from .device import (DeviceProfile, chip_env, keep_off_chips,
+                     measure_profile, sim_gpu_for)
 from .objects import (HEAD, LOST, REMOTE, ClusterRef, ObjectPlane,
                       TaskSpec)
 from .placement import PlacementScheduler, PlacementWeights, WorkerView
@@ -75,6 +79,22 @@ X64_FAILED = "x64-enable-failed"
 
 class ClusterTaskError(RuntimeError):
     pass
+
+
+def _free_ports(n: int) -> List[int]:
+    """``n`` distinct localhost TCP ports nothing listens on right now:
+    all ``n`` are held at once while they are picked, so the OS cannot
+    hand out one of them twice."""
+    socks = []
+    try:
+        for _ in range(n):
+            s = socket.socket()
+            socks.append(s)
+            s.bind(("localhost", 0))
+        return [s.getsockname()[1] for s in socks]
+    finally:
+        for s in socks:
+            s.close()
 
 
 @dataclass
@@ -124,13 +144,18 @@ class _TaskState:
 
 
 class _WorkerHandle:
-    def __init__(self, wid: int, proc, conn, sim_gpu: bool = False):
+    def __init__(self, wid: int, proc, conn, sim_gpu: bool = False,
+                 chip: Optional[int] = None):
         self.wid = wid
         self.proc = proc          # None for externally-joined workers
         self.conn = conn          # None while a TCP worker is attaching
         self.sim_gpu = sim_gpu   # respawns inherit the GPU pose
+        self.chip = chip         # accelerator chip it owns; respawns too
         self.profile: Optional[DeviceProfile] = None
         self.hello = threading.Event()
+        # why the worker refused its hello (assigned a chip it cannot
+        # see); set together with ``hello``
+        self.hello_error = ""
         # head_perf_counter − worker_perf_counter, estimated from the
         # t_mono stamps piggybacked on hello/pong replies (see
         # note_clock); None until the first stamped reply lands
@@ -153,9 +178,6 @@ class _WorkerHandle:
         # shipped there: a serving loop re-dispatching the same range
         # with unchanged rows sends a ("keep",) marker instead
         self.sliced_rows: Dict[tuple, str] = {}
-        # the hello carrying a failed-GPU-probe reason is counted into
-        # the faults scope once per worker, not once per re-profile
-        self.gpu_probe_fault_counted = False
         self.send_lock = threading.Lock()
 
     def note_clock(self, t_worker: float) -> None:
@@ -285,6 +307,7 @@ class ClusterRuntime:
                  weights: PlacementWeights = PlacementWeights(),
                  hello_timeout_s: float = 30.0,
                  sim_gpu_workers: Sequence[int] = (),
+                 device_workers: int = 0,
                  trace=None,
                  transport: str = "pipe",
                  address: Tuple[str, int] = ("127.0.0.1", 0),
@@ -298,16 +321,20 @@ class ClusterRuntime:
                  pipeline_depth: int = 2,
                  np_only: bool = False,
                  chaos: Optional[ChaosPlan] = None):
+        # the first ``device_workers`` workers each own one accelerator
+        # chip; every other worker, and this head, stays on the CPU
+        self.device_workers = max(0, int(device_workers))
+        sim_set = set(sim_gpu_workers) | {
+            i for i in range(workers) if sim_gpu_for(i)}
+        if self.device_workers:
+            keep_off_chips()
         if start_method is None:
-            # GPU-capable workers (real or posing) may execute jnp twin
-            # bodies, and XLA does not survive a fork of a head that has
-            # already touched jax — those fleets must spawn fresh
-            # interpreters. CPU-only fleets keep the fast fork default.
-            gpu_possible = (bool(sim_gpu_workers)
-                            or os.environ.get("REPRO_DISTRIB_SIM_GPU")
-                            or os.environ.get("REPRO_DISTRIB_PROBE_GPU")
-                            == "1")
-            if gpu_possible:
+            # workers that run jax (chip owners and sim-GPU posers) may
+            # execute jnp twin bodies, and XLA does not survive a fork
+            # of a head that has already touched jax — those fleets must
+            # spawn fresh interpreters. CPU-only fleets keep the fast
+            # fork default.
+            if self.device_workers or sim_set:
                 start_method = "spawn"
             else:
                 start_method = ("fork"
@@ -427,11 +454,19 @@ class ClusterRuntime:
             self.address = self.listener.address
             threading.Thread(target=self._accept_loop,
                              name="cluster-accept", daemon=True).start()
-        sim_set = set(sim_gpu_workers)
+        # the chip owners' runtime ports, picked together so they differ
+        ports = _free_ports(self.device_workers
+                            if self.device_workers > 1 else 0)
         for i in range(workers):
-            self._spawn_worker(sim_gpu=i in sim_set)
-        self._await_hellos(hello_timeout_s)
-        self._reprofile_sequentially()
+            self._spawn_worker(sim_gpu=i in sim_set,
+                               chip=i if i < self.device_workers else None,
+                               port=ports[i] if i < len(ports) else None)
+        try:
+            self._await_hellos(hello_timeout_s)
+            self._reprofile_sequentially()
+        except BaseException:
+            self.shutdown()
+            raise
         self._measure_transport()
         # liveness + deadline monitor (no-op work on an idle pipe fleet)
         threading.Thread(target=self._monitor_loop,
@@ -449,13 +484,21 @@ class ClusterRuntime:
             if len(self.fault_events) > 4096:
                 del self.fault_events[:2048]
 
-    def _spawn_worker(self, sim_gpu: bool = False) -> _WorkerHandle:
+    def _spawn_worker(self, sim_gpu: bool = False,
+                      chip: Optional[int] = None,
+                      port: Optional[int] = None) -> _WorkerHandle:
         from .worker import worker_main
         wid = next(self._wids)
         # resolve the env-var pose here (not in the worker): a respawn
         # gets a fresh wid that would no longer match the env wid list,
         # and the replacement must inherit its predecessor's pose
         sim_gpu = sim_gpu or sim_gpu_for(wid)
+        # a lone chip owner sees the host's default devices; several
+        # owners are each shown only their own chip
+        device_env = (None if chip is None
+                      else {} if self.device_workers == 1
+                      else chip_env(chip, port if port is not None
+                                    else _free_ports(1)[0]))
         if self.transport == "tcp":
             # the child dials back in over the socket; its handle has no
             # conn until the accept loop attaches it
@@ -466,13 +509,14 @@ class ClusterRuntime:
             endpoint = worker_conn
         proc = self._ctx.Process(
             target=worker_main,
-            args=(endpoint, wid, sim_gpu, self.hb_interval_s),
+            args=(endpoint, wid, sim_gpu, self.hb_interval_s, device_env),
             name=f"cluster-worker-{wid}", daemon=True)
         proc.start()
         if self.transport != "tcp":
             worker_conn.close()  # child's end lives in the child now
             head_conn = self._wrap_chaos(head_conn, wid)
-        wh = _WorkerHandle(wid, proc, head_conn, sim_gpu=sim_gpu)
+        wh = _WorkerHandle(wid, proc, head_conn, sim_gpu=sim_gpu,
+                           chip=chip)
         with self._lock:
             self._handles[wid] = wh
         if self.transport == "tcp":
@@ -593,6 +637,8 @@ class ClusterRuntime:
             if not wh.hello.wait(max(0.1, deadline - time.monotonic())):
                 raise TimeoutError(
                     f"worker {wh.wid} never said hello")
+            if wh.hello_error:
+                raise RuntimeError(wh.hello_error)
 
     def _reprofile_sequentially(self) -> None:
         """Startup hellos carry profiles measured while every worker was
@@ -602,14 +648,20 @@ class ClusterRuntime:
             handles = [wh for wh in self._handles.values() if wh.alive]
         for wh in handles:
             self._reprofile(wh)
+            if wh.hello_error:
+                raise RuntimeError(wh.hello_error)
 
     def _reprofile(self, wh: _WorkerHandle) -> None:
+        """Ask one worker to re-measure its profile. A chip owner whose
+        probe now fails answers ``hello_failed`` and exits; its handle
+        keeps the reason in ``hello_error`` (counted as a fault), and
+        the death that follows is not respawned."""
         wh.hello.clear()
         try:
             wh.send(("profile",))
         except OSError:
             return
-        wh.hello.wait(10.0)
+        wh.hello.wait(10.0 if wh.chip is None else 120.0)
 
     def _measure_transport(self, nbytes: int = 1 << 20) -> None:
         with self._lock:
@@ -695,15 +747,14 @@ class ClusterRuntime:
             wh.profile = DeviceProfile.from_dict(msg[1])
             if len(msg) > 2:
                 wh.note_clock(msg[2])
-            reason = getattr(wh.profile, "gpu_probe_error", "")
-            if reason and not wh.gpu_probe_fault_counted:
-                # the probe failing silently is how the 0.006x hetero
-                # regression hid: a "GPU" fleet quietly priced as CPUs
-                wh.gpu_probe_fault_counted = True
-                self._fault_event("gpu_probe_failures", wid=wh.wid,
-                                  reason=reason)
-                log.warning("worker %d GPU probe failed: %s",
-                            wh.wid, reason)
+            wh.hello.set()
+        elif kind == "hello_failed":
+            # a worker assigned a chip it cannot use refuses to pose as
+            # a CPU: the fleet must not quietly run without its device
+            wh.hello_error = msg[1]
+            self._fault_event("hello_failures", wid=wh.wid,
+                              reason=msg[1])
+            log.error("worker %d refused its hello: %s", wh.wid, msg[1])
             wh.hello.set()
         elif kind == "done":
             _, tid, oid, nbytes, payload = msg[:5]
@@ -867,10 +918,15 @@ class ClusterRuntime:
         self.worker_deaths += 1
         self._fault_event("worker_deaths", wid=wh.wid)
         self.plane.mark_worker_lost(wh.wid)
-        if self.respawn and wh.proc is not None:
+        if self.respawn and wh.proc is not None and not wh.hello_error:
+            if wh.chip is not None:
+                # the chip is free only once its last owner has exited
+                wh.proc.join(10.0)
             with obs.span("respawn", cat="fault", wid=wh.wid):
-                nw = self._spawn_worker(sim_gpu=wh.sim_gpu)
-                if nw.hello.wait(10.0):
+                nw = self._spawn_worker(sim_gpu=wh.sim_gpu, chip=wh.chip)
+                # a chip owner's hello waits for its device runtime
+                if (nw.hello.wait(10.0 if wh.chip is None else 120.0)
+                        and not nw.hello_error):
                     # the boot-time probe may have contended with
                     # whatever killed its predecessor: re-measure like
                     # at startup so chunk weights and profitability
@@ -1639,6 +1695,7 @@ class ClusterRuntime:
                 if twin is not None:
                     bodies[bk_obj.name] = twin
         candidates = tuple(b for b in bodies if b != "np")
+        dtypes = tuple(sorted({str(a.dtype) for a in arrays.values()}))
         t_split0 = time.perf_counter()
         parts_by = split_fn_variants(bodies, slice_names)
         t_split1 = time.perf_counter()
@@ -1675,8 +1732,14 @@ class ClusterRuntime:
         backends = cost_model.unit_backend_table(
             est_flops / len(views), per_bytes,
             [v.profile for v in views],
-            allow_jnp=bool(candidates), candidates=candidates)
+            allow_jnp=bool(candidates), candidates=candidates,
+            dtypes=dtypes)
         hetero = any(b != "np" for b in backends)
+        # a failed chunk degrades only onto bodies some live worker can
+        # run: a CPU-only fleet must never fall back onto a jax twin
+        runnable = {b for b in bodies if b == "np" or any(
+            backends_mod.feasible(backends_mod.get(b), v.profile, dtypes)
+            for v in views)}
         # register every blob this run may use: the chosen backends
         # plus each one's degradation-chain members ("np" always — it
         # is the terminal fallback); workers receive a blob only when a
@@ -1684,7 +1747,7 @@ class ClusterRuntime:
         need = set(backends) | {"np"}
         for bk in tuple(need):
             need.update(b for b in backends_mod.degradation_chain(bk)
-                        if b in bodies)
+                        if b in runnable)
         bids = {bk: self._blob_for(parts_by[bk]) for bk in sorted(need)}
         if tile:
             ranges = [range(t, min(t + tile, hi))
@@ -1758,7 +1821,7 @@ class ClusterRuntime:
                 # registry-ordered degradation chain (pallas → jnp →
                 # np): each erroring attempt pops one step off
                 chain = [b for b in backends_mod.degradation_chain(bk)
-                         if b in bodies]
+                         if b in runnable]
                 alt = tuple((b, bids[b], parts_by[b]) for b in chain)
             spec = TaskSpec(tid, "chunk", None, (), out,
                             blob_id=bids[bk],
@@ -2054,7 +2117,10 @@ class ClusterRuntime:
                 wh.send(("shutdown",))
             except OSError:
                 pass
-        deadline = time.monotonic() + 2.0
+        # a chip owner's exit includes the TPU runtime's teardown, which
+        # a terminate would interrupt
+        deadline = time.monotonic() + (60.0 if self.device_workers
+                                       else 2.0)
         for wh in handles:
             if wh.proc is None:
                 continue   # external worker: the shutdown message (or

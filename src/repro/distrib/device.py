@@ -9,9 +9,14 @@ profitability test in :mod:`repro.core.cost` consume these numbers; on a
 heterogeneous fleet the pfor sharder sizes chunks proportional to
 ``gflops``.
 
-GPU probing is gated behind ``REPRO_DISTRIB_PROBE_GPU=1`` because a jax
-import costs seconds per worker process; the offline container is
-CPU-only anyway.
+Which worker owns an accelerator chip is the head's decision, made when
+it spawns the worker (``ClusterRuntime(device_workers=...)``); a chip
+belongs to one process at a time. :func:`pin_process` applies that
+decision before jax loads a backend: every other worker is pinned to the
+CPU platform and never probes a device. An owning worker probes its chip
+in the dtype the chunk bodies run (f32) and reports ``device_kind``; one
+that finds no accelerator records why in ``gpu_probe_error``, and the
+worker refuses its hello with that reason instead of posing as a CPU.
 
 For laptops/CI, ``REPRO_DISTRIB_SIM_GPU`` makes jax-CPU workers *pose*
 as GPU workers so heterogeneous routing is exercisable anywhere:
@@ -27,9 +32,10 @@ from __future__ import annotations
 
 import os
 import socket
+import sys
 import time
 from dataclasses import asdict, dataclass
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import numpy as np
 
@@ -44,12 +50,15 @@ class DeviceProfile:
     gflops: float = 1.0            # measured matmul rate
     membw_gbs: float = 1.0         # measured copy bandwidth
     has_gpu: bool = False
-    gpu_kind: str = ""             # "cuda" / "tpu" / "sim" / ""
+    gpu_kind: str = ""             # platform: "tpu" / "gpu" / "sim" / ""
+    device_kind: str = ""          # jax device_kind, e.g. "TPU v5 lite"
+    visible_chips: str = ""        # the chip assignment this worker got
+    device_files: str = ""         # accelerator device files it holds open
     gpu_gflops: float = 0.0        # measured (or simulated) device rate
     transport_mbs: float = 0.0     # filled by the head's payload ping
     h2d_gbs: float = 0.0           # measured host→device staging bandwidth
     d2h_gbs: float = 0.0           # measured device→host gather bandwidth
-    gpu_probe_error: str = ""      # why the GPU probe failed (if it did)
+    gpu_probe_error: str = ""      # why the device probe failed
 
     def as_dict(self) -> Dict[str, Any]:
         return asdict(self)
@@ -67,29 +76,94 @@ def _probe_mem_bytes() -> int:
         return 0
 
 
-def _probe_gpu() -> tuple:
-    """(has_gpu, kind, gpu_gflops, h2d_gbs, d2h_gbs, error) — measured
-    on the real device.
+def chip_env(chip: int, port: int) -> Dict[str, str]:
+    """TPU runtime settings that give one process exactly one chip of a
+    multi-chip host: the chip's index, a one-chip process grid (a subset
+    of the host's chips, which is what lets the runtime load in several
+    processes side by side), and a port of its own for the runtime's
+    process service."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(port),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}"}
 
-    x64 is enabled *before* the timing matmul: the jnp twin workloads
-    this rate prices are float64 (PolyBench semantics), and an f32 probe
-    reads ~2x optimistic against them. Probe failures are returned as a
-    reason string — the head records it on the profile and counts it in
-    the faults scope instead of silently reporting a bare CPU."""
-    if os.environ.get("REPRO_DISTRIB_PROBE_GPU") != "1":
-        return False, "", 0.0, 0.0, 0.0, ""
+
+def pin_process(device_env: Optional[Dict[str, str]]) -> None:
+    """Fix this process's jax platform before jax loads a backend.
+
+    ``None`` pins the CPU platform. A dict (possibly empty) makes this
+    process the owner of one accelerator chip and applies the
+    chip-visibility settings it carries (:func:`chip_env`); the platform
+    itself is left to jax, so an owner that finds no chip says so."""
+    if device_env is None:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            jax.config.update("jax_platforms", "cpu")
+        return
+    os.environ.update(device_env)
+
+
+def keep_off_chips() -> None:
+    """Pin this process, a head whose workers own the chips, to jax's
+    CPU platform. Only this process is affected (workers are pinned by
+    :func:`pin_process`). A process that already holds an accelerator
+    cannot hand it over, so that is an error, not a silent downgrade."""
+    import jax
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        jax.config.update("jax_platforms", "cpu")
+    elif jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"this process already holds a {jax.default_backend()} "
+            f"device; create the ClusterRuntime that owns device workers "
+            f"before running anything on jax here")
+
+
+def _held_device_files() -> str:
+    """The accelerator device files this process holds open, comma
+    separated (Linux; empty elsewhere). A process shown one chip of a
+    multi-chip host sees it as chip 0 of a 1x1x1 topology, so these are
+    what tell several owners' chips apart."""
+    held = set()
+    try:
+        fds = os.listdir("/proc/self/fd")
+    except OSError:
+        return ""
+    for fd in fds:
+        try:
+            path = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:
+            continue
+        # /dev/vfio/vfio is the shared container, not a chip
+        if (path.startswith(("/dev/accel", "/dev/vfio/"))
+                and path != "/dev/vfio/vfio"):
+            held.add(path)
+    return ",".join(sorted(held))
+
+
+def _probe_device() -> tuple:
+    """(platform, kind, device_files, gflops, h2d_gbs, d2h_gbs, error)
+    of the accelerator this process owns, measured on the device.
+
+    The timing matmul runs in f32, the dtype the device bodies compute
+    in. A process that finds no accelerator returns the reason as the
+    error string, never a bare CPU profile."""
     try:
         import jax
-
-        # must precede any traced op, and matches the twins' f64 math
-        jax.config.update("jax_enable_x64", True)
         import jax.numpy as jnp
-        devs = [d for d in jax.devices()
-                if d.platform not in ("cpu",)]
-        if not devs:
-            return False, "", 0.0, 0.0, 0.0, "no non-cpu jax devices"
-        n = 512
-        a = jnp.ones((n, n), dtype=jnp.float64)
+
+        devs = jax.devices()
+        if devs[0].platform == "cpu":
+            return ("", "", "", 0.0, 0.0, 0.0,
+                    f"no accelerator visible: jax found only "
+                    f"{sorted({d.platform for d in devs})} (JAX_PLATFORMS="
+                    f"{os.environ.get('JAX_PLATFORMS', '')!r})")
+        dev = devs[0]
+        n = 1024
+        a = jnp.ones((n, n), dtype=jnp.float32)
         (a @ a).block_until_ready()   # compile + warm
         best = float("inf")
         for _ in range(3):
@@ -101,23 +175,24 @@ def _probe_gpu() -> tuple:
         # staging bandwidth, both directions — what the chunk pricing in
         # core.cost actually spends per chunk (8 MB, the blob-cache
         # sweep size, so the number reflects bulk transfers)
-        host = np.ones(1 << 20, dtype=np.float64)  # 8 MB
-        dev = jax.device_put(host)
-        dev.block_until_ready()
+        host = np.ones(1 << 21, dtype=np.float32)  # 8 MB
+        jax.device_put(host).block_until_ready()
         h2d = d2h = float("inf")
         for _ in range(3):
             t0 = time.perf_counter()
-            jax.device_put(host).block_until_ready()
+            on_dev = jax.device_put(host).block_until_ready()
             h2d = min(h2d, time.perf_counter() - t0)
+            # a fresh array each time: jax keeps the host copy of an
+            # array it has already transferred
             t0 = time.perf_counter()
-            np.asarray(dev)
+            np.asarray(on_dev)
             d2h = min(d2h, time.perf_counter() - t0)
         h2d_gbs = host.nbytes / max(1e-9, h2d) / 1e9
         d2h_gbs = host.nbytes / max(1e-9, d2h) / 1e9
-        return (True, devs[0].platform, round(gflops, 3),
-                round(h2d_gbs, 3), round(d2h_gbs, 3), "")
+        return (dev.platform, dev.device_kind, _held_device_files(),
+                round(gflops, 3), round(h2d_gbs, 3), round(d2h_gbs, 3), "")
     except Exception as exc:
-        return False, "", 0.0, 0.0, 0.0, f"{type(exc).__name__}: {exc}"
+        return ("", "", "", 0.0, 0.0, 0.0, f"{type(exc).__name__}: {exc}")
 
 
 def sim_gpu_for(wid: int) -> bool:
@@ -133,11 +208,12 @@ def sim_gpu_for(wid: int) -> bool:
         return False
 
 
-def measure_profile(wid: int, n: int = 128,
-                    sim_gpu: bool = None) -> DeviceProfile:
+def measure_profile(wid: int, n: int = 128, sim_gpu: bool = None,
+                    device: bool = False) -> DeviceProfile:
     """Micro-benchmark this process. ``n`` keeps the probe ~milliseconds.
     ``sim_gpu`` forces the simulated-GPU pose (None = consult the
-    ``REPRO_DISTRIB_SIM_GPU`` env var)."""
+    ``REPRO_DISTRIB_SIM_GPU`` env var). ``device`` says this process owns
+    an accelerator chip: only then is jax imported and the chip probed."""
     rng = np.random.default_rng(wid + 1)
     a = rng.normal(size=(n, n))
     b = rng.normal(size=(n, n))
@@ -161,8 +237,15 @@ def measure_profile(wid: int, n: int = 128,
         best = min(best, time.perf_counter() - t0)
     membw_gbs = 2.0 * buf.nbytes / max(1e-9, best) / 1e9  # read + write
 
-    (has_gpu, gpu_kind, gpu_gflops,
-     h2d_gbs, d2h_gbs, gpu_probe_error) = _probe_gpu()
+    gpu_kind = device_kind = device_files = gpu_probe_error = ""
+    visible_chips = ""
+    gpu_gflops = h2d_gbs = d2h_gbs = 0.0
+    if device:
+        # echo the head's assignment (chip_env) as this process got it
+        visible_chips = os.environ.get("TPU_VISIBLE_CHIPS", "")
+        (gpu_kind, device_kind, device_files, gpu_gflops, h2d_gbs,
+         d2h_gbs, gpu_probe_error) = _probe_device()
+    has_gpu = bool(gpu_kind)
     if sim_gpu is None:
         sim_gpu = sim_gpu_for(wid)
     if sim_gpu and not has_gpu:
@@ -184,6 +267,9 @@ def measure_profile(wid: int, n: int = 128,
         has_gpu=has_gpu,
         gpu_kind=gpu_kind,
         gpu_gflops=gpu_gflops,
+        device_kind=device_kind,
+        visible_chips=visible_chips,
+        device_files=device_files,
         h2d_gbs=h2d_gbs,
         d2h_gbs=d2h_gbs,
         gpu_probe_error=gpu_probe_error,

@@ -19,7 +19,7 @@ the same framing a TCP transport would use):
                  | ("ping", payload) | ("profile",) | ("shutdown",)
                  | ("rekey", authkey) | ("chaos", op, arg)
                  | ("welcome", wid) | ("denied", reason)   # handshake
-  worker → head: ("hello", profile, t_mono)
+  worker → head: ("hello", profile, t_mono) | ("hello_failed", reason)
                  | ("done", tid, oid, nbytes, payload, ran_backend,
                     spans_or_None, accel_stats_or_None)
                  | ("err", tid, message, traceback)
@@ -50,6 +50,7 @@ clock offset and land the spans on one aligned timeline.
 
 from __future__ import annotations
 
+import contextlib
 import pickle
 import sys
 import threading
@@ -59,8 +60,10 @@ from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
+from repro.core.backends import device_precision
+
 from . import accel
-from .device import measure_profile
+from .device import measure_profile, pin_process
 from .serial import assemble_fn, closure_arrays, loads_fn, rebase_chunk
 
 # results at or below this many bytes ride back inline with "done"
@@ -110,9 +113,11 @@ def _chunk_updates(body, lo: int, hi: int, written: Tuple[str, ...],
 
 
 class WorkerState:
-    def __init__(self, wid: int, sim_gpu: bool = False):
+    def __init__(self, wid: int, sim_gpu: bool = False,
+                 device: bool = False):
         self.wid = wid
         self.sim_gpu = sim_gpu    # pose as a GPU worker (hetero CI/demo)
+        self.device = device      # owns an accelerator chip
         self.objects: Dict[int, Any] = {}     # local object-plane shard
         self.blob_skel: Dict[int, bytes] = {}
         self.blob_cells: Dict[int, Dict[str, Any]] = {}
@@ -214,9 +219,11 @@ class WorkerState:
                 spans.append(("deserialize", t0, t1, None))
                 spans.append(("restore", t1, time.perf_counter(), None))
             self.chunks_run += 1
-            return _chunk_updates(body, lo, hi,
-                                  tuple(spec.get("written") or ()),
-                                  spans)
+            with (device_precision() if spec.get("backend", "np") != "np"
+                  else contextlib.nullcontext()):
+                return _chunk_updates(body, lo, hi,
+                                      tuple(spec.get("written") or ()),
+                                      spans)
         fn = loads_fn(spec["fn_blob"])
         args = self.resolve_args(spec["args"])
         self.tasks_run += 1
@@ -241,8 +248,28 @@ def _make_link(conn, wid: Optional[int], sim_gpu: bool):
     return PipeLink(conn)
 
 
+def _send_hello(link, state: WorkerState) -> bool:
+    """Measure this worker's profile and send it as a hello. A chip
+    owner whose device probe failed sends ``("hello_failed", reason)``
+    instead and returns False: it must leave rather than carry on as a
+    CPU worker."""
+    profile = measure_profile(state.wid, sim_gpu=state.sim_gpu or None,
+                              device=state.device)
+    if state.device and profile.gpu_probe_error:
+        link.send(("hello_failed",
+                   f"worker {state.wid} was assigned an accelerator chip "
+                   f"but cannot use one: {profile.gpu_probe_error}"))
+        return False
+    # the perf_counter stamp rides right next to the send so the head's
+    # receive-time-minus-stamp offset estimate is bounded by one one-way
+    # pipe latency, not by profile-measurement time
+    link.send(("hello", profile.as_dict(), time.perf_counter()))
+    return True
+
+
 def worker_main(conn, wid: Optional[int] = None, sim_gpu: bool = False,
-                hb_interval_s: float = 0.0) -> None:
+                hb_interval_s: float = 0.0,
+                device_env: Optional[Dict[str, str]] = None) -> None:
     """Entry point of the worker process. ``conn`` is an inherited pipe
     connection or a ``("tcp", (host, port), authkey)`` endpoint (the
     multi-host path — also reachable via ``python -m
@@ -252,16 +279,27 @@ def worker_main(conn, wid: Optional[int] = None, sim_gpu: bool = False,
     GPU-less hosts; the env var ``REPRO_DISTRIB_SIM_GPU`` (see
     :mod:`.device`) does the same by wid.
 
+    ``device_env`` is the head's chip assignment: ``None`` pins this
+    process to the CPU platform; a dict (the chip-visibility settings of
+    :func:`.device.chip_env`, or empty for a host's only device owner)
+    makes it the owner of one accelerator chip. An owner that finds no
+    chip sends ``("hello_failed", reason)`` instead of a hello and exits.
+
     With ``hb_interval_s > 0`` a daemon thread sends ``("hb", t_mono)``
     liveness beacons; they are ``droppable`` — a disconnected TCP window
     simply skips beats rather than queueing a burst for later."""
     from .transport import WorkerFencedError
+    pin_process(device_env)
+    device = device_env is not None
+    if device:
+        from repro.core.jaxcache import enable_compile_cache
+        enable_compile_cache()
     try:
         link = _make_link(conn, wid, sim_gpu)
     except (WorkerFencedError, OSError, EOFError):
         return   # head unreachable or this wid is fenced: nothing to do
     wid = getattr(link, "wid", wid) if wid is None else wid
-    state = WorkerState(wid, sim_gpu=sim_gpu)
+    state = WorkerState(wid, sim_gpu=sim_gpu, device=device)
     stop = threading.Event()
     hb_silenced = threading.Event()   # chaos: hang with silent beacons
 
@@ -275,12 +313,10 @@ def worker_main(conn, wid: Optional[int] = None, sim_gpu: bool = False,
         threading.Thread(target=_heartbeat, name=f"worker-hb-{wid}",
                          daemon=True).start()
     try:
-        # the perf_counter stamp rides right next to the send so the
-        # head's receive-time-minus-stamp offset estimate is bounded by
-        # one one-way pipe latency, not by profile-measurement time
-        link.send(("hello",
-                   measure_profile(wid, sim_gpu=sim_gpu or None)
-                   .as_dict(), time.perf_counter()))
+        if not _send_hello(link, state):
+            stop.set()
+            link.close()
+            return
     except (EOFError, OSError, BrokenPipeError):
         stop.set()
         return
@@ -348,10 +384,8 @@ def worker_main(conn, wid: Optional[int] = None, sim_gpu: bool = False,
             elif kind == "profile":
                 # re-measure on request: the head serializes these so
                 # fleet micro-benchmarks never contend with each other
-                link.send(("hello",
-                           measure_profile(state.wid,
-                                           sim_gpu=state.sim_gpu or None)
-                           .as_dict(), time.perf_counter()))
+                if not _send_hello(link, state):
+                    break
             elif kind == "rekey":
                 # the head rotated the transport authkey; future
                 # reconnects must present the new one

@@ -11,19 +11,21 @@ matched shape onto the corresponding seed Pallas kernel:
 * :func:`attention_rows` — unscaled-softmax row attention onto the
   flash kernel (``kernels/flash_attention``): the kernel bakes in a
   ``1/sqrt(d)`` score scale, so queries are pre-multiplied by
-  ``sqrt(d)`` to cancel it; block sizes are clamped to divisors because
-  the kernel refuses ragged tiles (zero-padding K would pollute the
-  softmax).
+  ``sqrt(d)`` to cancel it. Query rows are zero-padded to the query
+  block; keys cannot be (zero keys would pollute the softmax), so the
+  key block is a divisor of the key count that keeps the TPU's (8, 128)
+  tiling rule, or the whole key range.
 * :func:`scan_rows` — first-order linear recurrence onto the selective
   scan kernel (``kernels/mamba_scan``) via the identity mapping
   ``dt=1, B=C=1 (N=1), a=log(-log(c))`` which requires ``0<c<1``; an
   out-of-range coefficient raises, which the cluster counts as a
   lowering failure and degrades down the ``TaskSpec.alt`` chain.
 
-On CPU-only hosts the kernels run in Pallas *interpret* mode, so CI
-exercises the full routing path; a real ``pallas_call`` lowering is
-used when ``REPRO_DISTRIB_PROBE_GPU=1`` and jax actually sees an
-accelerator. ``REPRO_PALLAS_CHAOS=fail`` makes every entry point raise
+Whether a kernel compiles or runs in Pallas *interpret* mode follows
+the backend of the process that runs it
+(:func:`repro.kernels.interpret_mode`): compiled on a TPU, interpreted
+elsewhere, so CPU CI exercises the full routing path.
+``REPRO_PALLAS_CHAOS=fail`` makes every entry point raise
 (deterministic fallback-path tests).
 
 This module enables jax x64 itself: generated chunk bodies compute in
@@ -45,6 +47,7 @@ jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp  # noqa: E402  (after x64 so f64 survives)
 
+from . import interpret_mode  # noqa: E402
 from .flash_attention.flash_attention import flash_attention_bhsd  # noqa: E402
 from .mamba_scan import ops as _mamba_ops  # noqa: E402
 from .matmul import ops as _matmul_ops  # noqa: E402
@@ -73,14 +76,6 @@ def reset() -> None:
     _STATS.clear()
 
 
-def _use_interpret() -> bool:
-    """Interpret mode unless a real accelerator was probed *and* jax
-    actually sees one (mirrors the device layer's opt-in probe gate)."""
-    if os.environ.get("REPRO_DISTRIB_PROBE_GPU") != "1":
-        return True
-    return jax.default_backend() not in ("gpu", "tpu")
-
-
 def _chaos() -> None:
     if os.environ.get("REPRO_PALLAS_CHAOS") == "fail":
         raise RuntimeError("pallas-chaos")
@@ -92,19 +87,20 @@ def _count(interpret: bool) -> None:
         _bump("pallas_interpret_calls")
 
 
-def _div_block(n: int, pref: int) -> int:
-    """Largest block <= pref that divides n (kernels refuse ragged
-    tiles)."""
-    b = max(1, min(pref, n))
-    while n % b:
-        b -= 1
-    return b
+def _key_block(n: int, pref: int = 128) -> int:
+    """Key block for ``n`` keys: the largest divisor of ``n`` up to
+    ``pref`` that is a multiple of 8, else all ``n`` keys (a
+    full-extent block always meets the TPU's tiling rule)."""
+    for b in range(min(pref, n) // 8 * 8, 0, -8):
+        if n % b == 0:
+            return b
+    return n
 
 
 def matmul(a, b):
     """``a @ b`` through the blocked Pallas matmul kernel."""
     _chaos()
-    interpret = _use_interpret()
+    interpret = interpret_mode()
     _count(interpret)
     out = _matmul_ops.matmul(jnp.asarray(a), jnp.asarray(b),
                              force_pallas=True, interpret=interpret)
@@ -118,20 +114,24 @@ def attention_rows(q, k, v):
     with q ``(R, D)``, k ``(T, D)``, v ``(T, D)``.
     """
     _chaos()
-    interpret = _use_interpret()
+    interpret = interpret_mode()
     _count(interpret)
-    q = jnp.asarray(q)
-    k = jnp.asarray(k)
-    v = jnp.asarray(v)
+    return np.asarray(attention_block(jnp.asarray(q), jnp.asarray(k),
+                                      jnp.asarray(v), interpret=interpret))
+
+
+def attention_block(q, k, v, *, interpret: bool):
+    """:func:`attention_rows` on jax arrays (what compiles)."""
     rows, d = q.shape
-    skv = k.shape[0]
     # cancel the kernel's baked-in 1/sqrt(d) score scale
     qs = q * jnp.asarray(math.sqrt(d), q.dtype)
+    # query rows are independent: pad them to a whole number of blocks
+    bq = min(128, -(-rows // 8) * 8)
+    qs = jnp.pad(qs, ((0, (-rows) % bq), (0, 0)))
     out = flash_attention_bhsd(
         qs[None], k[None], v[None], causal=False, window=0, softcap=0.0,
-        bq=_div_block(rows, 128), bk=_div_block(skv, 128),
-        interpret=interpret)
-    return np.asarray(out[0])
+        bq=bq, bk=_key_block(k.shape[0]), interpret=interpret)
+    return out[0, :rows]
 
 
 def scan_rows(x_rows, c):
@@ -143,9 +143,14 @@ def scan_rows(x_rows, c):
         raise ValueError(
             f"pallas-lowering-infeasible: scan decay coefficient {c!r} "
             f"outside (0, 1) (a = log(-log(c)) undefined)")
-    interpret = _use_interpret()
+    interpret = interpret_mode()
     _count(interpret)
-    x_rows = jnp.asarray(x_rows)
+    return np.asarray(scan_block(jnp.asarray(x_rows), c,
+                                 interpret=interpret))
+
+
+def scan_block(x_rows, c: float, *, interpret: bool):
+    """:func:`scan_rows` on a jax array, ``0 < c < 1`` (what compiles)."""
     rows, length = x_rows.shape
     dtype = x_rows.dtype
     # identity mapping: B=1 batch, I=rows channels, N=1 state; with
@@ -158,4 +163,4 @@ def scan_rows(x_rows, c):
     d_skip = jnp.zeros((rows,), dtype)
     y = _mamba_ops.mamba_scan(x, dt, ones_n, ones_n, a, d_skip,
                               force_pallas=True, interpret=interpret)
-    return np.asarray(y[0]).T                        # (R, L)
+    return y[0].T                                    # (R, L)

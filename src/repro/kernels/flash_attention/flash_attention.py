@@ -19,10 +19,17 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import dot_precision
+
 NEG_INF = -1e30
+
+# block indices are int32 on the TPU; a bare ``0`` in an index map turns
+# int64 when x64 is on, and Mosaic refuses to lower that
+_ZERO = np.int32(0)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -40,7 +47,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     q = q_ref[0]                       # (bq, d)
     k = k_ref[0]                       # (bk, d)
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
+        q, k, (((1,), (1,)), ((), ())), precision=dot_precision(q.dtype),
         preferred_element_type=acc_dtype) * scale    # (bq, bk)
     if softcap and softcap > 0:
         s = jnp.tanh(s / softcap) * softcap
@@ -62,6 +69,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     m_ref[...] = m_new
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
         p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+        precision=dot_precision(v_ref.dtype),
         preferred_element_type=acc_dtype)
 
     @pl.when(ki == kv_steps - 1)
@@ -93,11 +101,11 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
         kern,
         grid=(bh, sq // bq, kv_steps),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, _ZERO)),
+            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, _ZERO)),
+            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, _ZERO)),
         ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, _ZERO)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), acc_dtype),

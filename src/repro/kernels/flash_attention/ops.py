@@ -8,20 +8,24 @@ otherwise the jnp oracle.
 
 from __future__ import annotations
 
-import jax
+from typing import Optional
+
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .flash_attention import flash_attention_bhsd
 from .ref import attention_ref
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, bq: int = 128, bk: int = 128,
-                    force_pallas: bool = False, interpret: bool = False):
-    on_tpu = jax.default_backend() == "tpu"
-    if not (on_tpu or force_pallas):
+                    force_pallas: bool = False,
+                    interpret: Optional[bool] = None):
+    if interpret_mode() and not force_pallas:
         return attention_ref(q, k, v, causal=causal, window=window,
                              softcap=softcap)
+    if interpret is None:   # tests pass it; else the process decides
+        interpret = interpret_mode()
     b, sq, h, d = q.shape
     skv, kvh = k.shape[1], k.shape[2]
     g = h // kvh
@@ -33,5 +37,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         b * h, skv, d)
     out = flash_attention_bhsd(
         qf, kf, vf, causal=causal, window=window, softcap=softcap,
-        bq=bq, bk=bk, interpret=interpret or not on_tpu)
+        bq=bq, bk=bk, interpret=interpret)
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import dot_precision
+
 
 def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref, *, k_steps: int,
                    acc_dtype):
@@ -25,6 +27,7 @@ def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref, *, k_steps: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(x_ref[...], y_ref[...],
+                            precision=dot_precision(x_ref.dtype),
                             preferred_element_type=acc_dtype)
 
     @pl.when(pl.program_id(2) == k_steps - 1)

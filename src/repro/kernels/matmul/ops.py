@@ -7,9 +7,11 @@ selected on TPU backends for MXU-aligned shapes; otherwise the jnp oracle
 
 from __future__ import annotations
 
-import jax
+from typing import Optional
+
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .matmul import matmul as _matmul_kernel
 from .ref import matmul_ref
 
@@ -23,17 +25,19 @@ def _pad_to(x, mult0, mult1):
 
 
 def matmul(x, y, *, bm: int = 128, bn: int = 128, bk: int = 512,
-           force_pallas: bool = False, interpret: bool = False):
+           force_pallas: bool = False,
+           interpret: Optional[bool] = None):
     """Matmul with kernel dispatch. On non-TPU backends the reference
     path runs unless ``force_pallas`` (tests use interpret=True)."""
-    on_tpu = jax.default_backend() == "tpu"
-    if not (on_tpu or force_pallas):
+    if interpret_mode() and not force_pallas:
         return matmul_ref(x, y)
+    if interpret is None:   # tests pass it; else the process decides
+        interpret = interpret_mode()
     m, k = x.shape
     _, n = y.shape
     bm_, bn_, bk_ = min(bm, m), min(bn, n), min(bk, k)
     xp = _pad_to(x, bm_, bk_)
     yp = _pad_to(y, bk_, bn_)
     out = _matmul_kernel(xp, yp, bm=bm_, bn=bn_, bk=bk_,
-                         interpret=interpret or not on_tpu)
+                         interpret=interpret)
     return out[:m, :n]

@@ -419,6 +419,9 @@ def sweep(archs, shapes, meshes, force=False) -> None:
 
 
 def main() -> None:
+    from repro.core.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

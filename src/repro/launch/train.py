@@ -30,6 +30,9 @@ from repro.train import AdamWConfig, init_opt_state, make_train_step
 
 
 def main(argv=None) -> dict:
+    from repro.core.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm_3b")
     ap.add_argument("--smoke", action="store_true",

@@ -206,8 +206,8 @@ def test_cluster_routes_chunks_to_toy(toy_backend):
 
 def test_broken_backend_degrades_down_alt_chain(boom_backend):
     """A backend whose chunks always raise must degrade chunk-by-chunk
-    down ``TaskSpec.alt`` (boom → jnp → np) and still produce correct
-    results — counted, not crashed."""
+    down ``TaskSpec.alt`` (boom → np here: the fleet runs no jnp twin)
+    and still produce correct results — counted, not crashed."""
     rng = np.random.default_rng(6)
     n, m = 14, 6
     A = rng.normal(size=(n, m))
@@ -224,6 +224,9 @@ def test_broken_backend_degrades_down_alt_chain(boom_backend):
         assert np.allclose(out, ref, atol=1e-8)
         ran = rt.stats()["chunks_executed"]
         assert ran.get("boom", 0) == 0
+        # no worker of this CPU fleet can run the jnp twin: the chain
+        # skips it and degrades straight to np
+        assert ran.get("jnp", 0) == 0
         assert sum(ran.values()) > 0     # degraded chunks completed
     finally:
         rt.shutdown()

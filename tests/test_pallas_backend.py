@@ -2,12 +2,9 @@
 seed Pallas kernels, roofline-priced against np/jnp, degrading down the
 ``TaskSpec.alt`` chain when a lowering fails — counted, not crashed.
 
-Interpret mode runs everywhere (CPU CI); the real-lowering validation
-at the bottom is gated behind ``REPRO_DISTRIB_PROBE_GPU=1`` on a host
-whose jax actually has a GPU/TPU backend.
+Interpret mode runs everywhere (CPU CI); the kernels' compiles for the
+chip are checked in ``tests/test_tpu_compile.py``.
 """
-
-import os
 
 import numpy as np
 import pytest
@@ -267,30 +264,3 @@ def test_runtime_infeasible_scan_coeff_degrades(monkeypatch):
     finally:
         rt.shutdown()
         ck.pfor_config.runtime = None
-
-
-# ---------------------------------------------------------------------------
-# real-GPU validation (carried satellite): opt-in, skipped on CPU hosts
-# ---------------------------------------------------------------------------
-
-_REAL_GPU = (os.environ.get("REPRO_DISTRIB_PROBE_GPU") == "1"
-             and jax.default_backend() in ("gpu", "tpu"))
-
-
-@pytest.mark.skipif(not _REAL_GPU,
-                    reason="real-GPU pallas lowering needs "
-                           "REPRO_DISTRIB_PROBE_GPU=1 and a jax "
-                           "GPU/TPU backend")
-def test_pallas_real_lowering_matches_interpret():
-    """On a real device the api surface compiles the kernels instead of
-    interpreting them; numerics must agree with numpy all the same."""
-    assert not api._use_interpret()
-    rng = np.random.default_rng(4)
-    A, B = rng.normal(size=(64, 32)), rng.normal(size=(32, 48))
-    got = np.asarray(api.matmul(A, B))
-    np.testing.assert_allclose(got, A @ B, atol=1e-8, rtol=1e-8)
-    api.reset()
-    api.matmul(A, B)
-    s = api.stats()
-    assert s.get("pallas_calls") == 1
-    assert s.get("pallas_interpret_calls", 0) == 0
