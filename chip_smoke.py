@@ -231,7 +231,7 @@ def _spy_jnp_results(ck) -> list:
 
 
 def _check_inproc(name: str, ck, seen: list) -> None:
-    rec = ck.history[-1]
+    rec = ck.last_dispatch
     say(f"{name}: dispatch variant={rec.variant} flops={rec.flops:.3e}")
     check(rec.variant == "jnp", f"{name}: dispatched {rec.variant!r}")
     outs = seen[-1] if isinstance(seen[-1], tuple) else (seen[-1],)

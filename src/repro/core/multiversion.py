@@ -15,10 +15,8 @@ at lower levels."
 
 from __future__ import annotations
 
-import time
-from collections import deque
-from dataclasses import dataclass, field
-from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -78,6 +76,12 @@ class DispatchRecord:
     profitable: bool
 
 
+# The timed phases of one call (``kernel.<phase>`` spans, histograms
+# ``phase.<phase>_s``). The whole-kernel jnp variant records all six;
+# the np and original variants only dispatch and body.
+PHASES = ("dispatch", "h2d", "body", "wait", "d2h", "copy_back")
+
+
 class CompiledKernel:
     """Callable decision tree over specialized variants.
 
@@ -109,9 +113,9 @@ class CompiledKernel:
             f"kernel.{self.__name__}")
         for v in variants.values():
             v.bind_metrics(self._mscope.sub("variants"))
-        # ring buffer: long-running serving processes dispatch millions
-        # of times; keep only the recent window
-        self.history: Deque[DispatchRecord] = deque(maxlen=10_000)
+        self._phases = obs.Phases(self._mscope, self.__name__, PHASES,
+                                  counts_bytes=("h2d", "d2h"))
+        self.last_dispatch: Optional[DispatchRecord] = None
         self._flop_cache: Dict[Tuple, float] = {}
         # dispatch stats watched by the profiler's specializer: per exact
         # call-signature counts + the decision the full tree made for it
@@ -237,6 +241,7 @@ class CompiledKernel:
             if isinstance(v, np.ndarray)})
 
     def __call__(self, *args, **kwargs):
+        clock = self._phases.start("dispatch")
         bound = self._bind(args, kwargs)
         sig = self._sig(bound)
         bucket_hit = False
@@ -270,12 +275,11 @@ class CompiledKernel:
             if sig in self.shape_counts:
                 self.last_decisions[sig] = (variant.name, rec.flops,
                                             rec.legality_ok)
-        self.history.append(rec)
+        self.last_dispatch = rec
         if self.pfor_config is not None:
             self.pfor_config.estimated_flops = rec.flops
-        t0 = time.perf_counter()
-        out = self._invoke(variant, bound)
-        dt = time.perf_counter() - t0
+        out = self._invoke(variant, bound, clock)
+        dt = clock.stop(after="dispatch")     # the variant's run
         variant.calls += 1
         variant.total_s += dt
         if spec is not None:
@@ -368,36 +372,68 @@ class CompiledKernel:
         bound = self._bind(args, kwargs)
         if self.pfor_config is not None:
             self.pfor_config.estimated_flops = self.estimate_flops(bound)
-        return self._invoke(self.variants[name], bound)
+        clock = self._phases.start()
+        out = self._invoke(self.variants[name], bound, clock)
+        clock.stop()
+        return out
 
-    def _invoke(self, variant: Variant, bound: Dict[str, Any]):
-        names = [n for n, _ in self.params]
-        args = [bound[n] for n in names]
-        if variant.name == "original":
-            return variant.fn(*args)
-        if variant.name == "jnp":
-            with backends.device_precision():
-                result = variant.fn(*args)
-        else:
-            result = variant.fn(*args)
+    def _invoke(self, variant: Variant, bound: Dict[str, Any],
+                clock: "obs.PhaseClock"):
+        args = [bound[n] for n, _ in self.params]
         gen = variant.generated
-        if gen is not None and gen.returns_written and result is not None:
+        writes = gen is not None and gen.returns_written
+        if variant.name != "jnp":
+            clock.next("body")
+            result = variant.fn(*args)
+            if not writes or result is None:
+                return result
             outs = result if isinstance(result, tuple) else (result,)
-            for name, val in zip(gen.written, outs):
-                orig = bound[name]
-                self._copy_back(orig, val)
+            self._write_back(gen, bound, [np.asarray(v) for v in outs])
             return None
-        return result
+        import jax
+
+        # the dispatcher owns the transfer in; the variant's own
+        # xp.asarray then finds device arrays and copies nothing. h2d
+        # times the issue of the copies: they run on into body and wait,
+        # since waiting for them here would hold back issuing the body.
+        clock.next("h2d")
+        clock.moved(sum(a.nbytes for a in args if isinstance(a, np.ndarray)))
+        args = [jax.device_put(a) if isinstance(a, np.ndarray) else a
+                for a in args]
+        clock.next("body")
+        with backends.device_precision():
+            result = variant.fn(*args)
+        if not writes or result is None:
+            return result
+        outs = result if isinstance(result, tuple) else (result,)
+        # np.asarray waits for the device anyway; the wait is made
+        # explicit so that d2h times the copy alone. The copy is queued
+        # before the wait so that it starts when the outputs are ready,
+        # not once the host has seen them ready, which would make the
+        # call slower than np.asarray alone.
+        clock.next("wait")
+        for v in outs:
+            if isinstance(v, jax.Array):
+                v.copy_to_host_async()
+        jax.block_until_ready(outs)
+        clock.next("d2h")
+        arrs = [np.asarray(v) for v in outs]
+        clock.moved(sum(a.nbytes for a in arrs))
+        clock.next("copy_back")
+        self._write_back(gen, bound, arrs)
+        return None
 
     @staticmethod
-    def _copy_back(orig, val):
-        arr = np.asarray(val)
-        if isinstance(orig, np.ndarray):
-            np.copyto(orig, arr.astype(orig.dtype, copy=False))
-        elif isinstance(orig, list):
-            data = arr.tolist()
-            orig[:] = data
-        # scalars: caller keeps its own copy; nothing to write back
+    def _write_back(gen: GeneratedVariant, bound: Dict[str, Any],
+                    arrs: List[np.ndarray]) -> None:
+        """Copy each written output into the caller's array or list;
+        scalars: the caller keeps its own copy, nothing to write back."""
+        for name, arr in zip(gen.written, arrs):
+            orig = bound[name]
+            if isinstance(orig, np.ndarray):
+                np.copyto(orig, arr.astype(orig.dtype, copy=False))
+            elif isinstance(orig, list):
+                orig[:] = arr.tolist()
 
     # -- introspection ------------------------------------------------------
     def source(self, backend: str = "np") -> str:

@@ -33,7 +33,7 @@ from collections import deque
 from typing import Any, Callable, Dict, Optional
 
 __all__ = ["Counter", "Gauge", "Histogram", "DictMetric", "Scope",
-           "MetricsRegistry", "registry"]
+           "MetricsRegistry", "registry", "record"]
 
 _LOCK = threading.Lock()
 
@@ -87,15 +87,22 @@ class Histogram:
 
     def observe(self, v: float) -> None:
         with _LOCK:
-            self.count += 1
-            self.total += v
-            self.min = v if self.min is None else min(self.min, v)
-            self.max = v if self.max is None else max(self.max, v)
-            self._window.append(v)
+            self._add(v)
+
+    def _add(self, v: float) -> None:
+        self.count += 1
+        self.total += v
+        self.min = v if self.min is None else min(self.min, v)
+        self.max = v if self.max is None else max(self.max, v)
+        self._window.append(v)
+
+    def recent(self) -> list:
+        """The observations still in the window, oldest first."""
+        with _LOCK:
+            return list(self._window)
 
     def percentile(self, q: float) -> Optional[float]:
-        with _LOCK:
-            window = sorted(self._window)
+        window = sorted(self.recent())
         if not window:
             return None
         idx = min(len(window) - 1, int(q / 100.0 * len(window)))
@@ -227,6 +234,16 @@ class MetricsRegistry:
 
 
 registry = MetricsRegistry()
+
+
+def record(observations, increments=()) -> None:
+    """Observe each ``(histogram, value)`` and add each ``(counter, n)``
+    under one hold of the registry lock."""
+    with _LOCK:
+        for h, v in observations:
+            h._add(v)
+        for c, n in increments:
+            c.value += n
 
 
 class MetricAttr:

@@ -5,6 +5,9 @@ export_chrome_trace` and renders the text instrument panel:
 
   * compile-pipeline stage totals per kernel (parse → SCoP →
     dependence → schedule → fusion → codegen → cache-store);
+  * per compiled kernel, its calls and the total and mean of each
+    phase of a call (``kernel.dispatch`` … ``kernel.copy_back``), with
+    the bytes moved host→device and device→host;
   * per-phase head totals across all pfor rounds (plan / split /
     dispatch / ship / gather / merge) with their share of round wall;
   * per-worker utilization — busy vs idle % over the traced rounds,
@@ -55,6 +58,7 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     chunk_evs = [ev for ev in events if ev["name"] == "chunk_inflight"]
     worker_evs = [ev for ev in events if ev.get("cat") == "worker"]
     compile_evs = [ev for ev in events if ev.get("cat") == "compile"]
+    kernel_evs = [ev for ev in events if ev.get("cat") == "kernel"]
 
     out: Dict[str, Any] = {}
 
@@ -65,6 +69,26 @@ def summarize(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         kernel = _args(ev).get("kernel", "?")
         compile_stages[kernel][ev["name"]] += _dur_s(ev)
     out["compile"] = {k: dict(v) for k, v in compile_stages.items()}
+
+    # -- compiled kernels' calls, phase by phase ----------------------------
+    kernels: Dict[str, Dict[str, Any]] = {}
+    for ev in kernel_evs:
+        a = _args(ev)
+        k = kernels.setdefault(a.get("kernel", "?"),
+                               {"calls": 0, "phases": {}})
+        phase = ev["name"].split(".", 1)[-1]
+        row = k["phases"].setdefault(phase, {"count": 0, "total_s": 0.0,
+                                             "bytes": 0})
+        row["count"] += 1
+        row["total_s"] += _dur_s(ev)
+        row["bytes"] += int(a.get("bytes", 0))
+        if phase == "body":          # every call of every variant has one
+            k["calls"] += 1
+    for k in kernels.values():
+        for row in k["phases"].values():
+            row["mean_s"] = round(row["total_s"] / row["count"], 9)
+            row["total_s"] = round(row["total_s"], 9)
+    out["kernels"] = kernels
 
     # -- head phase totals --------------------------------------------------
     round_wall = sum(_dur_s(ev) for ev in rounds)
@@ -168,6 +192,14 @@ def render(s: Dict[str, Any]) -> str:
                 f"{n} {d * 1e3:.1f}ms"
                 for n, d in sorted(stages.items(), key=lambda kv: -kv[1]))
             lines.append(f"  {kernel}: {stage_txt}")
+    for kernel, k in s["kernels"].items():
+        lines.append(f"== kernel {kernel} ({k['calls']} call(s)) ==")
+        for phase, row in k["phases"].items():
+            moved = (f"  {row['bytes'] / 1e6:.3f} MB"
+                     if row["bytes"] else "")
+            lines.append(f"  {phase:<9} {row['total_s'] * 1e3:9.3f}ms "
+                         f"total  {row['mean_s'] * 1e3:8.3f}ms mean"
+                         f"{moved}")
     lines.append(f"== head phases ({s['rounds_traced']} pfor round(s), "
                  f"wall {s['round_wall_s'] * 1e3:.1f}ms) ==")
     for name, row in s["phases"].items():

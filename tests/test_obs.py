@@ -3,6 +3,7 @@ cross-thread tokens, ring bound), clock-offset alignment, Chrome-trace
 export schema, the summarize analyzer, and the unified metrics registry
 backing the legacy stats()/telemetry() surfaces."""
 
+import glob
 import json
 import threading
 import time
@@ -11,7 +12,9 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.core.compiler import compile_kernel
+from repro.core import backends
+from repro.core.compiler import compile_kernel, optimize
+from repro.core.multiversion import PHASES
 from repro.distrib import ClusterRuntime
 from repro.distrib.cluster import _WorkerHandle
 from repro.obs import summarize
@@ -270,6 +273,29 @@ def test_registry_kind_conflict_raises():
         reg.scope("a").gauge("x")
 
 
+def test_phase_clock_tiles_a_call_and_counts_bytes(traced):
+    scope = obs.metrics.unique_scope("clocktest")
+    phases = obs.Phases(scope, "k", ("a", "b", "c"), counts_bytes=("b",))
+    clock = phases.start("a")
+    clock.next("b")
+    clock.moved(100)
+    clock.moved(28)
+    clock.next("c")
+    run_s = clock.stop(after="a")
+    snap = scope.snapshot()
+    assert [snap[f"phase.{p}_s"]["count"] for p in "abc"] == [1, 1, 1]
+    assert snap["b_bytes"] == 128
+    assert run_s == pytest.approx(snap["phase.b_s"]["total"]
+                                  + snap["phase.c_s"]["total"], abs=1e-9)
+    spans = [e for e in traced.events() if e.cat == "kernel"]
+    assert [e.name for e in spans] == ["kernel.a", "kernel.b", "kernel.c"]
+    assert spans[1].args == {"kernel": "k", "bytes": 128}
+    assert spans[0].args == {"kernel": "k"}
+    # one phase's end is the next one's start: the phases tile the call
+    for x, y in zip(spans, spans[1:]):
+        assert x.t1 == y.t0
+
+
 def test_counter_threaded_increments():
     c = Counter()
 
@@ -396,3 +422,127 @@ def test_live_cluster_trace_covers_every_chunk(tmp_path, traced):
     assert s_doc["rounds_traced"] >= 1
     for w, row in s_doc["workers"].items():
         assert row["run_spans"] > 0, f"{w} has no compute spans"
+
+
+# ---------------------------------------------------------------------------
+# the phases of a compiled kernel's call (last in the file: these run
+# jax, and the live cluster above forks its workers)
+# ---------------------------------------------------------------------------
+
+def _gemm(alpha: float, beta: float, C: "ndarray[f32,2]",
+          A: "ndarray[f32,2]", B: "ndarray[f32,2]",
+          NI: int, NJ: int, NK: int):
+    C[0:NI, 0:NJ] = beta * C[0:NI, 0:NJ] + alpha * np.dot(
+        A[0:NI, 0:NK], B[0:NK, 0:NJ])
+
+
+def _gemm_case(seed=0, ni=24, nj=16, nk=32):
+    """An optimize()d gemm that takes its whole-kernel jnp variant, and
+    operands of a known small shape."""
+    ck = optimize(_gemm)
+    ck.accel_threshold = 0.0
+    rng = np.random.default_rng(seed)
+    a = rng.standard_normal((ni, nk)).astype(np.float32)
+    b = rng.standard_normal((nk, nj)).astype(np.float32)
+    c = rng.standard_normal((ni, nj)).astype(np.float32)
+    return ck, (1.5, 1.2, c, a, b, ni, nj, nk)
+
+
+def _phase_counts(ck):
+    snap = obs.metrics.snapshot(ck._mscope.prefix)
+    return ({p: snap[f"phase.{p}_s"]["count"] for p in PHASES},
+            snap["h2d_bytes"], snap["d2h_bytes"])
+
+
+def test_kernel_call_records_each_phase_once_with_its_bytes():
+    ck, args = _gemm_case()
+    c0 = args[2].copy()
+    ck(*args)
+    assert ck.last_dispatch.variant == "jnp"
+    counts, h2d, d2h = _phase_counts(ck)
+    assert counts == {p: 1 for p in PHASES}
+    assert h2d == sum(a.nbytes for a in args if isinstance(a, np.ndarray))
+    assert d2h == args[2].nbytes
+    # the variant's own time is the sum of the phases after dispatch
+    snap = obs.metrics.snapshot(ck._mscope.prefix)
+    assert ck.variants["jnp"].total_s == pytest.approx(
+        sum(snap[f"phase.{p}_s"]["total"] for p in PHASES[1:]), abs=1e-9)
+    # the same output as the variant called on the NumPy operands, as
+    # the dispatcher called it before it owned the transfer in
+    with backends.device_precision():
+        (before,) = ck.variants["jnp"].fn(*args[:2], c0, *args[3:])
+    assert np.array_equal(args[2], np.asarray(before))
+    # a forced variant records its phases but no dispatch; the np
+    # variant records only its body
+    ck.call_variant("jnp", *args)
+    ck.call_variant("np", *args)
+    counts, h2d2, d2h2 = _phase_counts(ck)
+    assert counts == {"dispatch": 1, "h2d": 2, "body": 3, "wait": 2,
+                      "d2h": 2, "copy_back": 2}
+    assert (h2d2, d2h2) == (2 * h2d, 2 * d2h)
+
+
+def test_kernel_call_that_raises_records_no_phase():
+    ck, args = _gemm_case()
+    bad = list(args)
+    bad[4] = bad[4][:-1]             # B one row short of NK: the body fails
+    with pytest.raises(Exception):
+        ck.call_variant("jnp", *bad)
+    counts, h2d, d2h = _phase_counts(ck)
+    assert counts == {p: 0 for p in PHASES}
+    assert (h2d, d2h) == (0, 0)
+
+
+def test_kernel_phases_leave_the_ring_empty_when_dark():
+    assert not obs.enabled()
+    obs.recorder().clear()
+    ck, args = _gemm_case()
+    ck(*args)
+    ck(*args)
+    assert len(obs.recorder()) == 0
+    assert _phase_counts(ck)[0]["copy_back"] == 2
+
+
+def test_kernel_phases_are_host_events_of_a_profiler_trace(tmp_path):
+    import jax
+
+    ck, args = _gemm_case()
+    ck(*args)                        # compiles outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        ck(*args)
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                       recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    events = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                    for p in data.planes if p.name.startswith("/host:")
+                    for ln in p.lines for e in ln.events
+                    if e.name.startswith("kernel."))
+    assert [name for _, _, name in events] == [f"kernel.{p}"
+                                               for p in PHASES]
+    for (_, end, _), (start, _, _) in zip(events, events[1:]):
+        assert end <= start
+
+
+def test_summarize_reports_each_kernel_phase(tmp_path, traced, capsys):
+    ck, args = _gemm_case()
+    ck(*args)
+    ck(*args)
+    spans = [e for e in traced.events() if e.cat == "kernel"]
+    assert [e.name for e in spans[:6]] == [f"kernel.{p}" for p in PHASES]
+    assert spans[1].args == {"kernel": "_gemm",
+                             "bytes": _phase_counts(ck)[1] // 2}
+    path = str(tmp_path / "trace.json")
+    obs.export_chrome_trace(path)
+    s = summarize.summarize(summarize.load_events(path))
+    k = s["kernels"]["_gemm"]
+    assert k["calls"] == 2
+    assert set(k["phases"]) == set(PHASES)
+    assert k["phases"]["h2d"]["count"] == 2
+    assert k["phases"]["h2d"]["bytes"] == _phase_counts(ck)[1]
+    assert k["phases"]["d2h"]["bytes"] == 2 * args[2].nbytes
+    assert k["phases"]["body"]["mean_s"] == pytest.approx(
+        k["phases"]["body"]["total_s"] / 2, abs=1e-9)
+    assert summarize.main([path]) == 0
+    out = capsys.readouterr().out
+    assert "== kernel _gemm (2 call(s)) ==" in out
+    assert "copy_back" in out

@@ -161,7 +161,7 @@ def test_from_trace_entry_point():
     tmp = np.zeros(M)
     ck(A, x, y, tmp, M, N)
     np.testing.assert_allclose(y, A.T @ (A @ x), atol=1e-8)
-    assert ck.history[-1].legality_ok
+    assert ck.last_dispatch.legality_ok
 
 
 # ---------------------------------------------------------------------------
@@ -384,8 +384,8 @@ def test_original_fallback_preserved_after_specialization():
     bad = A.astype(np.int64)                # dtype violates legality
     C = C0.copy()
     ck(C, bad, B, 1.0, 1.0, n, n, n)
-    assert ck.history[-1].variant == "original"
-    assert not ck.history[-1].legality_ok
+    assert ck.last_dispatch.variant == "original"
+    assert not ck.last_dispatch.legality_ok
 
 
 # ---------------------------------------------------------------------------

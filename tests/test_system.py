@@ -43,7 +43,7 @@ def test_end_to_end_profile_compile_dispatch_restart(tmp_path):
         profiled(data0.copy(), cov, mean, M, N)
         np.testing.assert_allclose(cov, ref_cov, atol=1e-8)
     assert profiled.compiled is not None
-    assert profiled.compiled.history[-1].legality_ok
+    assert profiled.compiled.last_dispatch.legality_ok
 
     cache_dir = str(tmp_path / "vcache")
     hints = synthesize_hints(profiled.trace)
@@ -80,7 +80,7 @@ def test_end_to_end_correlation_paper_flow():
         np.testing.assert_allclose(
             np.asarray(t_args[2], float), np.asarray(ref_args[2], float),
             atol=1e-7, err_msg=f"correlation {style} corr matrix")
-        assert ck.history[-1].legality_ok
+        assert ck.last_dispatch.legality_ok
 
 
 @pytest.mark.slow
