@@ -2,9 +2,13 @@
 d_ff=24576, vocab=65536, MoE 16e top-2, Mamba:attn 7:1 interleave
 [arXiv:2403.19887; hf].
 
-Period-8 superblock: attention at index 4, Mamba elsewhere; MoE FFN on odd
-indices. Runs long_500k (hybrid → sub-quadratic: Mamba state + 9 attention
-layers with KV cache)."""
+Period-8 superblock: attention at index 4 with no positional encoding,
+Mamba elsewhere; MoE FFN on odd indices, a dense MLP on the others. The
+Mamba layers are the published Mamba-1 mixer Jamba2-Mini also runs
+(causal conv1d of width 4 with its conv state, a rank-512 ``dt_proj``,
+RMSNorm on Δ, B and C; ``d_state`` 16, ``expand`` 2). Runs long_500k
+(hybrid → sub-quadratic: Mamba state + 9 attention layers with KV
+cache)."""
 
 from repro.models.common import ArchConfig
 
@@ -17,8 +21,9 @@ def config() -> ArchConfig:
         period=8, attn_idx=4,
         n_experts=16, experts_topk=2, expert_d_ff=24576,
         moe_every=2, moe_offset=1,
-        ssm_state=16, ssm_expand=2,
+        ssm_state=16, ssm_expand=2, ssm_conv=4, ssm_dt_rank=512,
         mlp_act="silu", tie_embeddings=False,
+        use_rope=False, scale_embed=False,
         microbatch=16, remat="full", fused_xent=True, opt_8bit=True,
         seq_shard=True,
     )
@@ -31,6 +36,6 @@ def smoke_config() -> ArchConfig:
         vocab=512, period=4, attn_idx=2,
         n_experts=4, experts_topk=2, expert_d_ff=64,
         moe_every=2, moe_offset=1, ssm_state=4, ssm_expand=2,
-        tie_embeddings=False,
+        tie_embeddings=False, use_rope=False, scale_embed=False,
         microbatch=1, remat="none", attn_chunk=64,
     )

@@ -1,4 +1,4 @@
-"""Architecture and shape registry: the 10 assigned (arch × shape) grids.
+"""Architecture and shape registry: the assigned (arch × shape) grids.
 
 Shapes (LM family):
   train_4k     seq 4,096   global_batch 256   → train_step
@@ -44,6 +44,7 @@ ARCHS = [
     "llava_next_mistral_7b",
     "jamba_1_5_large_398b",
     "xlstm_125m",
+    "jamba2_mini",
 ]
 
 # accept dashed ids from the assignment table too
@@ -59,6 +60,8 @@ _ALIASES.update({
     "llava-next-mistral-7b": "llava_next_mistral_7b",
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
     "xlstm-125m": "xlstm_125m",
+    "jamba2-mini": "jamba2_mini",
+    "ai21-jamba2-mini": "jamba2_mini",
 })
 
 

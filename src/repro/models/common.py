@@ -56,12 +56,22 @@ class ArchConfig:
     attn_softcap: float = 0.0
     logit_softcap: float = 0.0
     rope_theta: float = 10000.0
+    use_rope: bool = True        # False: attention with no positional
+                                 # encoding (jamba)
+    scale_embed: bool = True     # multiply embeddings by sqrt(d_model)
     # hybrid (jamba): period pattern of layer kinds
     period: int = 1
     attn_every: int = 1          # attention at idx % period == attn_idx
     attn_idx: int = 0
     ssm_state: int = 16          # mamba state size
     ssm_expand: int = 2
+    ssm_conv: int = 4            # mamba causal conv1d width
+    ssm_dt_rank: int = 0         # rank of the delta projection
+                                 # (0 → ceil(d_model / 16), Mamba's rule)
+    experts_held: Optional[Tuple[int, int]] = None
+    # ^ [lo, hi) of the routed experts this chip holds (expert
+    #   parallelism); None = all of them. The router keeps all
+    #   n_experts outputs; the layer computes only its own experts' part.
     # xlstm
     xlstm_pattern: Tuple[str, ...] = ()
     # encoder-decoder
@@ -71,6 +81,9 @@ class ArchConfig:
     embeds_input: bool = False
     # numerics / training
     dtype: Any = jnp.bfloat16
+    residual_dtype: Any = None   # the residual stream's dtype (None:
+                                 # dtype); each layer's input is normed
+                                 # back to dtype
     norm_eps: float = 1e-6
     tie_embeddings: bool = True
     # parallelism knobs (filled by configs; tuned by hillclimb)
@@ -126,6 +139,14 @@ class ArchConfig:
             return self.sliding_window if idx_in_period % 2 == 0 else 0
         return self.sliding_window  # 0 = no window; mistral: all layers
 
+    @property
+    def dt_rank(self) -> int:
+        return self.ssm_dt_rank or -(-self.d_model // 16)
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.n_experts)
+
     def padded_vocab(self, tp: int = 16, align: int = 128) -> int:
         q = tp * align
         return ((self.vocab + q - 1) // q) * q
@@ -144,8 +165,10 @@ class ArchConfig:
                     + self.n_heads * self.head_dim * d
             elif kind == "mamba":
                 inner = self.ssm_expand * d
+                n, r = self.ssm_state, self.dt_rank
                 total_l = d * inner * 2 + inner * d \
-                    + inner * (2 * self.ssm_state + 2)
+                    + inner * (r + 2 * n) + r * inner \
+                    + inner * (self.ssm_conv + n + 3)
             elif kind in ("mlstm", "slstm"):
                 inner = d * 2
                 total_l = 4 * d * inner + inner * d
@@ -153,10 +176,11 @@ class ArchConfig:
                 total_l = 0
             if self.layer_is_moe(p):
                 eff = self.expert_d_ff or self.d_ff
-                total_l += self.n_experts * 3 * d * eff
+                lo, hi = self.held_range
+                total_l += (hi - lo) * 3 * d * eff
                 total_l += self.n_shared_experts * 3 * d * eff
                 total_l += d * self.n_experts  # router
-            elif kind == "attn" and self.d_ff > 0:
+            elif self.d_ff > 0 and kind in ("attn", "mamba"):
                 mult = 3 if self.mlp_act in ("silu", "gelu") else 2
                 total_l += mult * d * self.d_ff
             total += total_l * self.n_periods
@@ -170,8 +194,10 @@ class ArchConfig:
             return self.param_count()
         d = self.d_model
         eff = self.expert_d_ff or self.d_ff
-        per_layer_all = self.n_experts * 3 * d * eff
-        per_layer_act = (self.experts_topk + self.n_shared_experts) \
+        lo, hi = self.held_range
+        per_layer_all = (hi - lo) * 3 * d * eff
+        per_layer_act = (min(self.experts_topk, hi - lo)
+                         + self.n_shared_experts) \
             * 3 * d * eff
         n_moe_layers = sum(1 for p in range(self.period)
                            if self.layer_is_moe(p)) * self.n_periods
@@ -203,10 +229,12 @@ class KeyGen:
 # ---------------------------------------------------------------------------
 
 def rmsnorm(x, scale, eps=1e-6):
+    """RMSNorm in float32; the output takes the scale's dtype (the
+    model's), so a float32 residual stream feeds its matmuls in it."""
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
                    keepdims=True)
     out = x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
-    return (out * (1.0 + scale.astype(jnp.float32))).astype(x.dtype)
+    return (out * (1.0 + scale.astype(jnp.float32))).astype(scale.dtype)
 
 
 def rope(x, positions, theta=10000.0):
@@ -390,16 +418,20 @@ def init_attn(kg: KeyGen, cfg: ArchConfig) -> Tuple[Dict, Dict]:
 
 
 def apply_attn(p, x, cfg: ArchConfig, *, positions, window=0, cache=None,
-               cross_kv=None):
+               cross_kv=None, valid=None):
     """x: (B, S, D). cache: dict(k, v, index) for decode. cross_kv: (k, v)
-    for encoder-decoder cross attention (ignores cache/causal)."""
+    for encoder-decoder cross attention (ignores cache/causal). valid:
+    (B,) count of real tokens at the front of each row (the rest pad a
+    fixed-size prefill chunk); the cache index advances by it."""
     h = rmsnorm(x, p["ln"], cfg.norm_eps)
     q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
     if cfg.qkv_bias:
         q = q + p["bq"]
+    pos_enc = (lambda t: rope(t, positions, cfg.rope_theta)) \
+        if cfg.use_rope else (lambda t: t)
     if cross_kv is not None:
         k, v = cross_kv
-        q = rope(q, positions, cfg.rope_theta)
+        q = pos_enc(q)
         out = plain_attention(q, k, v, causal=False, window=0,
                               cap=cfg.attn_softcap)
     else:
@@ -408,8 +440,8 @@ def apply_attn(p, x, cfg: ArchConfig, *, positions, window=0, cache=None,
         if cfg.qkv_bias:
             k = k + p["bk"]
             v = v + p["bv"]
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q = pos_enc(q)
+        k = pos_enc(k)
         if cache is not None:
             # index: scalar () = uniform decode position (steady-state
             # serving; tiny dynamic-update-slice writes), or (B,) =
@@ -432,12 +464,16 @@ def apply_attn(p, x, cfg: ArchConfig, *, positions, window=0, cache=None,
                 cv = jnp.where(sel, v.astype(cache["v"].dtype),
                                cache["v"])
             else:
-                # per-slot prefill always fills from position 0
+                # a multi-token write (prefill, or one chunk of it):
+                # the rows start together, at the first row's index
                 ck = jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], k.astype(cache["k"].dtype), 0, axis=1)
+                    cache["k"], k.astype(cache["k"].dtype), idx[0],
+                    axis=1)
                 cv = jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], v.astype(cache["v"].dtype), 0, axis=1)
-            cache = {"k": ck, "v": cv, "index": idx + s_new}
+                    cache["v"], v.astype(cache["v"].dtype), idx[0],
+                    axis=1)
+            step = s_new if valid is None else valid.astype(idx.dtype)
+            cache = {"k": ck, "v": cv, "index": idx + step}
             out = attention(q, ck, cv, cfg, causal=True, window=window,
                             q_offset=idx)
         else:

@@ -2,9 +2,10 @@
 
 The sequence recurrence is the one iteration dimension the planner must
 never shard (knowledge-base entry 'ssm_scan': sequential on seq, parallel
-on batch/feature). Training uses an associative scan (log-depth, lowers to
-compact HLO); decode keeps an explicit recurrent state — which is why these
-families run the long_500k shape that quadratic attention cannot.
+on batch/feature). A whole sequence runs as a blocked associative scan
+that carries the state between blocks; decode and chunked prefill keep an
+explicit recurrent state — which is why these families run the long_500k
+shape that quadratic attention cannot.
 """
 
 from __future__ import annotations
@@ -19,91 +20,191 @@ from .common import ArchConfig, KeyGen, _init, rmsnorm
 
 
 # ---------------------------------------------------------------------------
-# Mamba (selective SSM, Mamba-1 style simplified)
+# Mamba-1 mixer, as Jamba publishes it (JambaMambaMixer)
 # ---------------------------------------------------------------------------
+#
+#   (x, z) = in_proj(h)                       no bias
+#   x = silu(causal depthwise conv1d(x))      width ssm_conv, with bias
+#   (Δ, B, C) = x_proj(x)                     ranks dt_rank, N, N
+#   Δ, B, C = RMSNorm(Δ), RMSNorm(B), RMSNorm(C)   (Jamba's stabiliser)
+#   Δ = softplus(dt_proj(Δ) + dt_bias);  A = -exp(A_log)
+#   s_t = exp(Δ_t A) s_{t-1} + Δ_t x_t B_t ;  y_t = s_t C_t + D x_t
+#   out = out_proj(y · silu(z))
+#
+# The scan runs in float32. Decode state per sequence: the last
+# ssm_conv - 1 conv inputs and the (inner, N) recurrent state.
+
+SCAN_CHUNK = 128   # prefill scan block: memory ∝ block, not prompt
+
+
+def _uniform(key, shape, bound, dtype):
+    return jax.random.uniform(key, shape, jnp.float32, -bound,
+                              bound).astype(dtype)
+
 
 def init_mamba(kg: KeyGen, cfg: ArchConfig) -> Tuple[Dict, Dict]:
+    """Random weights drawn as Mamba's own init sets them: A_log =
+    log(1..N) per channel, D = 1, dt_bias = softplus⁻¹(Δ₀) with Δ₀
+    log-uniform in [1e-3, 1e-1], dt_proj uniform in ±dt_rank^-½, the
+    conv as PyTorch's Conv1d default (uniform in ±1/√width)."""
     d = cfg.d_model
     inner = cfg.ssm_expand * d
-    n = cfg.ssm_state
+    n, r, k = cfg.ssm_state, cfg.dt_rank, cfg.ssm_conv
+    dt0 = jnp.exp(jax.random.uniform(
+        kg(), (inner,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    dt0 = jnp.maximum(dt0, 1e-4)
     p = {
+        "ln": jnp.zeros((d,), cfg.dtype),
         "in_proj": _init(kg(), (d, 2 * inner), cfg.dtype),
-        "x_proj": _init(kg(), (inner, 2 * n + 1), cfg.dtype),
-        "dt_bias": jnp.zeros((inner,), jnp.float32),
+        "conv_w": _uniform(kg(), (k, inner), 1 / math.sqrt(k), cfg.dtype),
+        "conv_b": _uniform(kg(), (inner,), 1 / math.sqrt(k), cfg.dtype),
+        "x_proj": _init(kg(), (inner, r + 2 * n), cfg.dtype),
+        "dt_ln": jnp.zeros((r,), cfg.dtype),
+        "b_ln": jnp.zeros((n,), cfg.dtype),
+        "c_ln": jnp.zeros((n,), cfg.dtype),
+        "dt_proj": _uniform(kg(), (r, inner), r ** -0.5, cfg.dtype),
+        "dt_bias": dt0 + jnp.log(-jnp.expm1(-dt0)),
         "a_log": jnp.log(jnp.broadcast_to(
             jnp.arange(1, n + 1, dtype=jnp.float32), (inner, n))),
         "d_skip": jnp.ones((inner,), jnp.float32),
         "out_proj": _init(kg(), (inner, d), cfg.dtype),
-        "ln": jnp.zeros((d,), cfg.dtype),
     }
     s = {
+        "ln": ("embed",),
         "in_proj": ("embed", "inner"),
+        "conv_w": ("ssm", "inner"),
+        "conv_b": ("inner",),
         "x_proj": ("inner", "ssm"),
+        "dt_ln": ("ssm",),
+        "b_ln": ("ssm",),
+        "c_ln": ("ssm",),
+        "dt_proj": ("ssm", "inner"),
         "dt_bias": ("inner",),
         "a_log": ("inner", "ssm"),
         "d_skip": ("inner",),
         "out_proj": ("inner", "embed"),
-        "ln": ("embed",),
     }
     return p, s
 
 
-def _mamba_scan_train(xz, dt, B, C, a, d_skip, use_pallas=False):
-    """Associative scan over seq. xz:(B,L,I) dt:(B,L,I) B/C:(B,L,N)."""
-    if use_pallas:
-        from repro.kernels.mamba_scan import ops as scan_ops
+def selective_scan(u, dt, Bm, Cm, a, h0, chunk: int = SCAN_CHUNK):
+    """s_t = exp(Δ_t a) s_{t-1} + Δ_t u_t B_t ; y_t = s_t · C_t, from
+    state ``h0``. u/dt: (B, L, I) f32; Bm/Cm: (B, L, N); a: (I, N), the
+    negative decay; h0: (B, I, N). Returns (y (B, L, I), s_L).
 
-        return scan_ops.mamba_scan(xz, dt, B, C, a, d_skip)
-    # h_t = A_t * h_{t-1} + B_t x_t ; associative over (A, Bx)
-    a_bar = jnp.exp(dt[..., None] * (-jnp.exp(a))[None, None])  # (B,L,I,N)
-    bx = (dt * xz)[..., None] * B[..., None, :]                 # (B,L,I,N)
+    Blocks of ``chunk`` steps run as an associative scan and carry the
+    state from block to block, so memory grows with the block and not
+    with L. A step with Δ = 0 leaves the state as it was: padding."""
+    b, l, inner = u.shape
+    if l == 1:
+        h = jnp.exp(dt[:, 0, :, None] * a) * h0 \
+            + (dt[:, 0] * u[:, 0])[..., None] * Bm[:, 0, None, :]
+        return (h * Cm[:, 0, None, :]).sum(-1)[:, None], h
+    c = min(chunk, l)
+    pad = (-l) % c
+    if pad:
+        widths = ((0, 0), (0, pad), (0, 0))
+        u, dt, Bm, Cm = (jnp.pad(t, widths) for t in (u, dt, Bm, Cm))
+    nb = (l + pad) // c
+
+    def blocks(t):
+        return jnp.moveaxis(t.reshape((b, nb, c) + t.shape[2:]), 1, 0)
 
     def combine(c1, c2):
         a1, b1 = c1
         a2, b2 = c2
         return a1 * a2, a2 * b1 + b2
 
-    a_cum, h = jax.lax.associative_scan(combine, (a_bar, bx), axis=1)
-    y = (h * C[..., None, :]).sum(-1)                           # (B,L,I)
-    return y + d_skip[None, None] * xz
+    def body(h, blk):
+        ub, dtb, bb, cb = blk
+        a_bar = jnp.exp(dtb[..., None] * a)                  # (B,c,I,N)
+        bx = (dtb * ub)[..., None] * bb[:, :, None, :]
+        a_cum, h_loc = jax.lax.associative_scan(combine, (a_bar, bx),
+                                                axis=1)
+        hs = h_loc + a_cum * h[:, None]
+        return hs[:, -1], (hs * cb[:, :, None, :]).sum(-1)
+
+    h, ys = jax.lax.scan(body, h0, tuple(blocks(t)
+                                         for t in (u, dt, Bm, Cm)))
+    y = jnp.moveaxis(ys, 0, 1).reshape(b, nb * c, inner)[:, :l]
+    return y, h
 
 
-def apply_mamba(p, x, cfg: ArchConfig, state: Optional[Dict] = None):
-    """x: (B, S, D). state (decode): {'h': (B, I, N)}."""
+def _token_mask(valid, b: int, s: int):
+    """(B, S) bool: which positions hold real tokens (None = all)."""
+    if valid is None:
+        return None
+    return jnp.arange(s)[None, :] < valid[:, None]
+
+
+def apply_mamba(p, x, cfg: ArchConfig, state: Optional[Dict] = None,
+                valid=None):
+    """x: (B, S, D). state: {'conv': (B, K-1, I), 'h': (B, I, N)} to
+    continue from (decode, or the next chunk of a prefill), else None
+    (a whole sequence from zero state: training). valid: (B,) count of
+    real tokens at the front of each row; the padding after them leaves
+    both states as they were. Returns (x + out, new state or None)."""
     b, s, d = x.shape
     inner = cfg.ssm_expand * d
-    n = cfg.ssm_state
-    h0 = rmsnorm(x, p["ln"], cfg.norm_eps)
-    proj = jnp.einsum("bsd,di->bsi", h0, p["in_proj"])
-    xz, gate = jnp.split(proj, 2, axis=-1)
-    xz = jax.nn.silu(xz)
-    dbc = jnp.einsum("bsi,ik->bsk", xz, p["x_proj"]).astype(jnp.float32)
-    dt = jax.nn.softplus(dbc[..., 0:1] + p["dt_bias"][None, None])
-    Bm, Cm = dbc[..., 1:1 + n], dbc[..., 1 + n:]
-    a = p["a_log"]
+    n, r, k = cfg.ssm_state, cfg.dt_rank, cfg.ssm_conv
+    eps = cfg.norm_eps
+    h_in = rmsnorm(x, p["ln"], eps)
+    proj = jnp.einsum("bsd,di->bsi", h_in, p["in_proj"])
+    xs, gate = jnp.split(proj, 2, axis=-1)
 
-    if state is None:
-        y = _mamba_scan_train(xz.astype(jnp.float32), dt, Bm, Cm, a,
-                              p["d_skip"], cfg.use_pallas)
+    # causal depthwise conv over [carried inputs | this call's inputs]
+    prev = jnp.zeros((b, k - 1, inner), xs.dtype) if state is None \
+        else state["conv"].astype(xs.dtype)
+    xc = jnp.concatenate([prev, xs], axis=1)                  # (B,S+K-1,I)
+    w = p["conv_w"].astype(jnp.float32)
+    conv = p["conv_b"].astype(jnp.float32) + sum(
+        xc[:, j:j + s].astype(jnp.float32) * w[j] for j in range(k))
+    u = jax.nn.silu(conv).astype(cfg.dtype)                   # (B,S,I)
+
+    dbc = jnp.einsum("bsi,ik->bsk", u, p["x_proj"])
+    dt_in, Bm, Cm = jnp.split(dbc, [r, r + n], axis=-1)
+    dt_in = rmsnorm(dt_in, p["dt_ln"], eps)
+    Bm = rmsnorm(Bm, p["b_ln"], eps).astype(jnp.float32)
+    Cm = rmsnorm(Cm, p["c_ln"], eps).astype(jnp.float32)
+    dt = jax.nn.softplus(
+        jnp.einsum("bsr,ri->bsi", dt_in, p["dt_proj"]).astype(jnp.float32)
+        + p["dt_bias"])
+    mask = _token_mask(valid, b, s)
+    if mask is not None:
+        dt = jnp.where(mask[..., None], dt, 0.0)
+    a = -jnp.exp(p["a_log"])
+    u32 = u.astype(jnp.float32)
+
+    if state is None and cfg.use_pallas:
+        from repro.kernels.mamba_scan import ops as scan_ops
+
+        y = scan_ops.mamba_scan(u32, dt, Bm, Cm, p["a_log"], p["d_skip"])
         new_state = None
     else:
-        # single-token decode: s == 1
-        a_bar = jnp.exp(dt[:, 0, :, None] * (-jnp.exp(a))[None])
-        bx = (dt[:, 0] * xz[:, 0].astype(jnp.float32))[..., None] \
-            * Bm[:, 0, None, :]
-        h = a_bar * state["h"] + bx                       # (B, I, N)
-        y = (h * Cm[:, 0, None, :]).sum(-1)[:, None]      # (B,1,I)
-        y = y + p["d_skip"][None, None] * xz.astype(jnp.float32)
-        new_state = {"h": h}
+        h0 = jnp.zeros((b, inner, n), jnp.float32) if state is None \
+            else state["h"]
+        y, h = selective_scan(u32, dt, Bm, Cm, a, h0)
+        y = y + p["d_skip"] * u32
+        new_state = None
+        if state is not None:
+            # the last K-1 conv inputs before each row's end
+            end = jnp.full((b,), s, jnp.int32) if valid is None \
+                else valid.astype(jnp.int32)
+            conv_state = jax.vmap(
+                lambda row, e: jax.lax.dynamic_slice_in_dim(
+                    row, e, k - 1, axis=0))(xc, end)
+            new_state = {"conv": conv_state.astype(state["conv"].dtype),
+                         "h": h}
 
-    y = (y * jax.nn.silu(gate.astype(jnp.float32))).astype(x.dtype)
+    y = (y * jax.nn.silu(gate.astype(jnp.float32))).astype(cfg.dtype)
     out = jnp.einsum("bsi,id->bsd", y, p["out_proj"])
-    return x + out, new_state
+    return x + out.astype(x.dtype), new_state
 
 
 def init_mamba_state(cfg: ArchConfig, batch: int) -> Dict:
     inner = cfg.ssm_expand * cfg.d_model
-    return {"h": jnp.zeros((batch, inner, cfg.ssm_state), jnp.float32)}
+    return {"conv": jnp.zeros((batch, cfg.ssm_conv - 1, inner), cfg.dtype),
+            "h": jnp.zeros((batch, inner, cfg.ssm_state), jnp.float32)}
 
 
 # ---------------------------------------------------------------------------

@@ -113,20 +113,22 @@ def init_params(cfg: ArchConfig, key) -> Tuple[Dict, Dict]:
 # ---------------------------------------------------------------------------
 
 def _apply_layer(p, x, cfg: ArchConfig, idx_in_period: int, *, positions,
-                 cache=None, enc_out=None):
+                 cache=None, enc_out=None, valid=None):
+    """One layer. Returns (x, new cache, MoE stats or None)."""
     kind = cfg.layer_kind(idx_in_period)
     new_cache = None
+    stats = None
     if kind == "attn":
         window = cfg.layer_window(idx_in_period)
         x, new_cache = apply_attn(p["attn"], x, cfg, positions=positions,
                                   window=window,
                                   cache=None if cache is None
-                                  else cache.get("kv"))
+                                  else cache.get("kv"), valid=valid)
         new_cache = None if new_cache is None else {"kv": new_cache}
     elif kind == "mamba":
         x, st = ssm_mod.apply_mamba(p["mamba"], x, cfg,
                                     None if cache is None
-                                    else cache.get("ssm"))
+                                    else cache.get("ssm"), valid=valid)
         new_cache = None if st is None else {"ssm": st}
     elif kind == "mlstm":
         x, st = ssm_mod.apply_mlstm(p["mlstm"], x, cfg,
@@ -151,16 +153,32 @@ def _apply_layer(p, x, cfg: ArchConfig, idx_in_period: int, *, positions,
             if new_cache is not None:
                 new_cache["cross_kv"] = ckv
     if "moe" in p:
-        x = moe_mod.apply_moe(p["moe"], x, cfg)
+        x, stats = moe_mod.apply_moe(p["moe"], x, cfg, valid=valid)
     elif "mlp" in p:
         x = apply_mlp(p["mlp"], x, cfg)
-    return x, new_cache
+    return x, new_cache, stats
+
+
+def _sum_stats(per_layer: List[Dict]) -> Dict:
+    """MoE stats of a period's layers: per-expert counts stacked
+    (layers, experts), the scalars summed."""
+    if not per_layer:
+        return {}
+    out = {"expert_counts": jnp.stack([st["expert_counts"]
+                                       for st in per_layer])}
+    for key in ("assign_held", "assign_total", "dropped"):
+        out[key] = sum(st[key] for st in per_layer)
+    return out
 
 
 def forward(params, x, cfg: ArchConfig, *, positions, caches=None,
-            enc_out=None):
+            enc_out=None, valid=None, with_stats: bool = False):
     """x: (B, S, D) embeddings. caches: list per idx_in_period of stacked
-    cache pytrees (leading dim n_periods) or None. Returns (x, caches)."""
+    cache pytrees (leading dim n_periods) or None. valid: (B,) count of
+    real tokens at the front of each row (None = all). Returns (x,
+    caches), or (x, caches, stats) ``with_stats``: the MoE layers'
+    ``expert_counts`` (moe layers, held experts) and summed
+    ``assign_held``, ``assign_total``, ``dropped``."""
     blocks = params["blocks"]
 
     def seq_constraint(x):
@@ -191,15 +209,19 @@ def forward(params, x, cfg: ArchConfig, *, positions, caches=None,
         x = seq_constraint(x)
         bp = xs[0]
         cc = xs[1] if caches is not None else [None] * cfg.period
-        new_cc = []
+        new_cc, per_layer = [], []
         for i in range(cfg.period):
-            x, nc = _apply_layer(bp[i], x, cfg, i, positions=positions,
-                                 cache=cc[i], enc_out=enc_out)
+            x, nc, st = _apply_layer(bp[i], x, cfg, i, positions=positions,
+                                     cache=cc[i], enc_out=enc_out,
+                                     valid=valid)
             new_cc.append(nc)
+            if st is not None:
+                per_layer.append(st)
         x = seq_constraint(x)
-        if caches is not None:
-            return x, new_cc
-        return x, None
+        ys = new_cc if caches is not None else None
+        if with_stats:
+            return x, (ys, _sum_stats(per_layer))
+        return x, ys
 
     if cfg.remat == "full":
         body = jax.checkpoint(body)
@@ -210,8 +232,16 @@ def forward(params, x, cfg: ArchConfig, *, positions, caches=None,
             body,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
     xs = (blocks,) if caches is None else (blocks, caches)
-    x, new_caches = jax.lax.scan(body, x, xs)
-    return x, new_caches
+    x, ys = jax.lax.scan(body, x, xs)
+    if not with_stats:
+        return x, ys
+    new_caches, stats = ys
+    if stats:
+        counts = stats["expert_counts"]
+        stats = {"expert_counts": counts.reshape((-1,) + counts.shape[2:]),
+                 **{k: v.sum() for k, v in stats.items()
+                    if k != "expert_counts"}}
+    return x, new_caches, stats
 
 
 def encode(params, src_embeds, cfg: ArchConfig):
@@ -243,8 +273,10 @@ def encode(params, src_embeds, cfg: ArchConfig):
 
 
 def embed_tokens(params, tokens, cfg: ArchConfig):
-    return params["embed"][tokens] * jnp.asarray(
-        math.sqrt(cfg.d_model), cfg.dtype)
+    x = params["embed"][tokens]
+    if cfg.scale_embed:
+        x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.dtype)
+    return x.astype(cfg.residual_dtype or cfg.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -327,10 +359,9 @@ def prefill(params, batch, cfg: ArchConfig, max_seq: int):
     return caches, logits
 
 
-def decode_step(params, tokens, caches, cfg: ArchConfig, *, enc_out=None):
-    """One decode step. tokens: (B, 1) int32. Returns (logits, caches)."""
-    x = embed_tokens(params, tokens, cfg)
-    # position from any attention cache index (all layers share it)
+def _positions(caches, x):
+    """Positions of this call's tokens, from any attention cache index
+    (all layers share it); zeros where there is none."""
     pos0 = None
     for c in caches:
         if c is not None and "kv" in c:
@@ -339,15 +370,44 @@ def decode_step(params, tokens, caches, cfg: ArchConfig, *, enc_out=None):
     if pos0 is None:
         pos0 = jnp.zeros((x.shape[0],), jnp.int32)
     if pos0.ndim == 0:  # uniform decode position
-        positions = pos0 + jnp.arange(x.shape[1])
-    else:
-        positions = pos0[:, None] + jnp.arange(x.shape[1])[None, :]
-    x, caches = forward(params, x, cfg, positions=positions,
-                        caches=caches, enc_out=enc_out)
+        return pos0 + jnp.arange(x.shape[1])
+    return pos0[:, None] + jnp.arange(x.shape[1])[None, :]
+
+
+def _head(params, x, cfg: ArchConfig):
+    """Final norm + unembedding: (B, T, D) → (B, T, Vpad) logits."""
+    from .common import mask_padded_vocab, softcap
+
     x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
     w = params.get("unembed", params["embed"])
-    from .common import mask_padded_vocab, softcap
     logits = jnp.einsum("btd,vd->btv", x, w)
-    logits = mask_padded_vocab(softcap(logits, cfg.logit_softcap),
-                               cfg.vocab)[:, 0]
-    return logits, caches
+    return mask_padded_vocab(softcap(logits, cfg.logit_softcap), cfg.vocab)
+
+
+def prefill_chunk(params, tokens, caches, cfg: ArchConfig, valid):
+    """One fixed-size chunk of a prefill, continuing each row from its
+    caches (KV written at the cache index; conv and recurrent state
+    carried in and out). tokens: (B, C) int32, of which the first
+    ``valid[b]`` are real and the rest padding. Returns (caches, logits
+    at each row's last real token (B, Vpad), MoE stats)."""
+    x = embed_tokens(params, tokens, cfg)
+    x, caches, stats = forward(params, x, cfg,
+                               positions=_positions(caches, x),
+                               caches=caches, valid=valid, with_stats=True)
+    last = jnp.take_along_axis(
+        x, (valid.astype(jnp.int32) - 1)[:, None, None], axis=1)
+    return caches, _head(params, last, cfg)[:, 0], stats
+
+
+def decode_step(params, tokens, caches, cfg: ArchConfig, *, enc_out=None,
+                valid=None, with_stats: bool = False):
+    """One decode step. tokens: (B, 1) int32; valid: (B,) 1 for rows
+    that advance, 0 for rows whose caches stay as they were (None = all
+    advance). Returns (logits, caches), or (logits, caches, MoE stats)
+    ``with_stats``."""
+    x = embed_tokens(params, tokens, cfg)
+    out = forward(params, x, cfg, positions=_positions(caches, x),
+                  caches=caches, enc_out=enc_out, valid=valid,
+                  with_stats=with_stats)
+    logits = _head(params, out[0], cfg)[:, 0]
+    return (logits,) + tuple(out[1:])

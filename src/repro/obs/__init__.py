@@ -126,27 +126,31 @@ class Phases:
     """The timed phases of a compiled kernel's calls, bound once per
     kernel to the histograms ``phase.<name>_s`` of its metrics scope and,
     for each name in ``counts_bytes``, to the counter ``<name>_bytes``.
+    ``prefix`` names the events and the ring's category (``kernel`` by
+    default; the serving engine's ticks use ``tick``).
 
     ``clock = phases.start("dispatch")`` begins a call's first phase,
     ``clock.next(name)`` ends the running phase and begins the next, and
     ``clock.stop()`` ends the last one and records the call: always each
     phase's seconds into its histogram and the bytes given to
     ``clock.moved`` into its counter, under one lock; with tracing on
-    also a ring span per phase (``cat="kernel"``, args ``kernel`` and
+    also a ring span per phase (``cat=prefix``, args ``kernel`` and
     ``bytes``). While a JAX profiler session records (looked up once,
-    at ``start``), each phase is also the host event ``kernel.<name>``
+    at ``start``), each phase is also the host event ``<prefix>.<name>``
     in that trace, on the clock of the device ops. The event name is
     fixed; per-call values go only in the ring's args. A call that
     raises before ``stop`` records nothing."""
 
-    __slots__ = ("kernel", "hists", "counters", "events")
+    __slots__ = ("kernel", "hists", "counters", "events", "cat")
 
-    def __init__(self, scope, kernel: str, names, counts_bytes=()):
+    def __init__(self, scope, kernel: str, names, counts_bytes=(),
+                 prefix: str = "kernel"):
         self.kernel = kernel
         self.hists = {n: scope.histogram(f"phase.{n}_s") for n in names}
         self.counters = {n: scope.counter(f"{n}_bytes")
                          for n in counts_bytes}
-        self.events = {n: f"kernel.{n}" for n in names}
+        self.events = {n: f"{prefix}.{n}" for n in names}
+        self.cat = prefix
 
     def start(self, name: Optional[str] = None) -> "PhaseClock":
         clock = PhaseClock(self, _profiler_annotation())
@@ -201,7 +205,7 @@ class PhaseClock:
             for (name, t0, n), t1 in zip(marks, ends):
                 args = {"kernel": ph.kernel, "bytes": n} if n else \
                     {"kernel": ph.kernel}
-                _recorder.record(ph.events[name], "kernel", t0, t1,
+                _recorder.record(ph.events[name], ph.cat, t0, t1,
                                  args=args)
         if after is None:
             return t_end - marks[0][1]

@@ -579,9 +579,11 @@ class ClusterLMEngine:
             return 0
         n_slots = self.slots.n_slots
         tokens = np.zeros((n_slots, 1), np.int32)
+        valid = np.zeros((n_slots,), np.int32)
         for slot, req in self.active.items():
             tokens[slot, 0] = req.generated[-1]
-        next_tokens = self._roll(remote_lm.lm_decode, tokens)
+            valid[slot] = 1
+        next_tokens = self._roll(remote_lm.lm_decode, tokens, valid)
         done_slots = []
         for slot, req in self.active.items():
             tok = int(next_tokens[slot])

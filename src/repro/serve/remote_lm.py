@@ -21,10 +21,13 @@ per decode step. Pickling (lineage anchors, head fetches for
 re-anchoring) converts jax leaves to numpy so a Resident crosses
 processes without a live jax runtime on the sending side's devices.
 
-The decode math is a transplant of :class:`repro.serve.engine.ServeEngine`
-(same prefill → argmax → insert → batched decode ordering, same
-explicit-dtype model code), which is what makes the cluster engine's
-token streams **bitwise-identical** to the single-process engine.
+The decode math is the single-process engine's own: the programs of
+:func:`repro.serve.engine.lm_steps` (chunked prefill into a one-slot
+cache → insert → batched decode with greedy sampling inside the step)
+driven by :func:`repro.serve.engine.prefill_into`, which is what makes
+the cluster engine's token streams **bitwise-identical** to
+:class:`repro.serve.engine.ServeEngine`. The worker's programs do not
+donate their caches: lineage may still hold an older state.
 """
 
 from __future__ import annotations
@@ -38,7 +41,7 @@ __all__ = ["Resident", "lm_boot", "lm_prefill", "lm_decode", "lm_out",
 # repro.distrib.worker.INLINE_MAX stays in the worker object store
 _RESIDENT_FLOOR = 1 << 16
 
-_JITS: dict = {}   # (cfg.name, dtype, max_seq) → (prefill, decode, insert)
+_JITS: dict = {}   # (cfg.name, dtype, max_seq) → engine.LMSteps
 
 
 def tree_np(tree):
@@ -78,28 +81,15 @@ class Resident:
 
 
 def _jits_for(cfg, max_seq: int):
-    """Per-(config, max_seq) jitted prefill/decode/insert, cached for
-    the life of the worker process — call 2 of a serving loop hits a
-    compiled executable."""
+    """Per-(config, max_seq) serving programs, cached for the life of
+    the worker process — call 2 of a serving loop hits a compiled
+    executable."""
     key = (cfg.name, str(getattr(cfg, "dtype", "")), int(max_seq))
     entry = _JITS.get(key)
     if entry is None:
-        import jax
-        from repro.models import transformer as T
+        from repro.serve.engine import lm_steps
 
-        def _prefill(params, tokens):
-            return T.prefill(params, {"tokens": tokens}, cfg, max_seq)
-
-        def _decode(params, tokens, caches):
-            return T.decode_step(params, tokens, caches, cfg)
-
-        def _insert(caches, one, slot):
-            def ins(big, small):
-                return jax.lax.dynamic_update_slice_in_dim(
-                    big, small.astype(big.dtype), slot, axis=1)
-            return jax.tree.map(ins, caches, one)
-
-        entry = (jax.jit(_prefill), jax.jit(_decode), jax.jit(_insert))
+        entry = lm_steps(cfg, max_seq, donate=False)
         _JITS[key] = entry
     return entry
 
@@ -113,28 +103,24 @@ def _boot_impl(params, cfg, n_slots: int, max_seq: int) -> Resident:
 
 
 def _prefill_impl(res: Resident, prompt, slot: int) -> Resident:
-    import jax.numpy as jnp
+    from repro.serve.engine import prefill_into
     st = res.value
-    jit_prefill, _, jit_insert = _jits_for(st["cfg"], st["max_seq"])
-    tokens = jnp.asarray(prompt, jnp.int32)[None]
-    one_cache, logits = jit_prefill(st["params"], tokens)
-    tok = int(jnp.argmax(logits[0]))
-    caches = jit_insert(st["caches"], one_cache, jnp.int32(slot))
+    steps = _jits_for(st["cfg"], st["max_seq"])
+    caches, tok, _, _ = prefill_into(steps, st["params"], st["caches"],
+                                     prompt, slot)
     new = dict(st)
     new["caches"] = caches
     return Resident(new, out=np.asarray([tok], np.int32))
 
 
-def _decode_impl(res: Resident, tokens) -> Resident:
-    import jax.numpy as jnp
+def _decode_impl(res: Resident, tokens, valid) -> Resident:
     st = res.value
-    _, jit_decode, _ = _jits_for(st["cfg"], st["max_seq"])
-    logits, caches = jit_decode(st["params"], jnp.asarray(tokens),
-                                st["caches"])
-    next_tokens = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+    steps = _jits_for(st["cfg"], st["max_seq"])
+    nxt, _, caches, _ = steps.decode(st["params"], np.asarray(tokens),
+                                     np.asarray(valid), st["caches"])
     new = dict(st)
     new["caches"] = caches
-    return Resident(new, out=next_tokens)
+    return Resident(new, out=np.asarray(nxt, np.int32))
 
 
 # -- shipped entry points (reference only module-level functions) -----------
@@ -149,9 +135,10 @@ def lm_prefill(state, prompt, slot):
     return _prefill_impl(state, prompt, slot)
 
 
-def lm_decode(state, tokens):
-    """One batched decode tick; out = next token per slot."""
-    return _decode_impl(state, tokens)
+def lm_decode(state, tokens, valid):
+    """One batched decode tick of the slots with ``valid`` 1; out =
+    next token per slot."""
+    return _decode_impl(state, tokens, valid)
 
 
 def lm_out(state):

@@ -338,7 +338,8 @@ def test_histogram_snapshot_has_p95():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
-def test_cluster_lm_decode_matches_serve_engine_exactly():
+@pytest.mark.parametrize("arch", ["stablelm_3b", "jamba2_mini"])
+def test_cluster_lm_decode_matches_serve_engine_exactly(arch):
     import jax
 
     from repro.configs import get_smoke_config
@@ -346,7 +347,7 @@ def test_cluster_lm_decode_matches_serve_engine_exactly():
     from repro.serve import ClusterLMEngine
     from repro.serve.engine import Request, ServeEngine
 
-    cfg = get_smoke_config("stablelm_3b")
+    cfg = get_smoke_config(arch)
     params, _ = T.init_params(cfg, jax.random.key(0))
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab, int(rng.integers(4, 12)))

@@ -143,3 +143,47 @@ def test_jnp_twin_pfor_jit_step_compiles(one_chip):
     with backends.device_precision():
         c = accel.vmapped(iter_fn).lower(*args).compile()
     assert c.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("tokens", [16, 512], ids=["decode", "chunk"])
+def test_jamba_expert_layer_compiles(one_chip, tokens):
+    """Jamba2-Mini's dropless expert layer at its published widths, 8 of
+    16 experts held, in bf16: the grouped matmuls become the chip's
+    kernel, and the layer's temporaries stay small beside the 2.8 GB of
+    expert weights it reads."""
+    from repro.configs.jamba2_mini import ep_config
+    from repro.models import moe
+
+    cfg = ep_config()
+    d, f, e = cfg.d_model, cfg.expert_d_ff, 8
+    bf16 = jnp.bfloat16
+    shapes = [((1, tokens, d), bf16), ((d, 16), jnp.float32),
+              ((e, d, f), bf16), ((e, d, f), bf16), ((e, f, d), bf16),
+              ((d,), bf16)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+
+    def layer(x, router, wi, wg, wo, ln):
+        p = {"router": router, "wi": wi, "wg": wg, "wo": wo, "ln": ln}
+        return moe.apply_moe(p, x, cfg)
+
+    c = jax.jit(layer).lower(*args).compile()
+    assert _has_kernel(c)
+    assert c.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+def test_jamba_mamba_decode_compiles(one_chip):
+    """One decode token of the published Mamba-1 mixer for 16 slots,
+    with its conv and recurrent state, in bf16 with the scan in f32."""
+    from repro.configs.jamba2_mini import ep_config
+    from repro.models import ssm
+
+    cfg = ep_config()
+    p_shapes = jax.eval_shape(
+        lambda: ssm.init_mamba(ssm.KeyGen(jax.random.key(0)), cfg)[0])
+    st_shapes = jax.eval_shape(lambda: ssm.init_mamba_state(cfg, 16))
+    on = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((16, 1, cfg.d_model), jnp.float32,
+                             sharding=one_chip)
+    c = jax.jit(lambda p, x, st: ssm.apply_mamba(p, x, cfg, st)).lower(
+        jax.tree.map(on, p_shapes), x, jax.tree.map(on, st_shapes)).compile()
+    assert c.memory_analysis().temp_size_in_bytes < 0.1e9
