@@ -417,6 +417,17 @@ def init_attn(kg: KeyGen, cfg: ArchConfig) -> Tuple[Dict, Dict]:
     return p, s
 
 
+def write_rows(cache, new, idx):
+    """cache: (B, S, ...); new: (B, 1, ...); idx: (B,). Row b's one
+    position goes to ``cache[b, idx[b]]``; an index at or past S writes
+    nothing. A scatter of B positions, in place when the cache's buffer
+    is donated (a select over all S positions would rewrite the whole
+    cache to put in B of them)."""
+    rows = jnp.arange(cache.shape[0])
+    return cache.at[rows, idx].set(new[:, 0].astype(cache.dtype),
+                                   mode="drop")
+
+
 def apply_attn(p, x, cfg: ArchConfig, *, positions, window=0, cache=None,
                cross_kv=None, valid=None):
     """x: (B, S, D). cache: dict(k, v, index) for decode. cross_kv: (k, v)
@@ -445,24 +456,21 @@ def apply_attn(p, x, cfg: ArchConfig, *, positions, window=0, cache=None,
         if cache is not None:
             # index: scalar () = uniform decode position (steady-state
             # serving; tiny dynamic-update-slice writes), or (B,) =
-            # per-slot positions (continuous batching; elementwise
-            # one-hot select — a scatter here would force GSPMD into
-            # involuntary full rematerialization)
+            # per-slot positions (continuous batching; one position per
+            # row, scattered into the cache in place; under GSPMD the
+            # scatter partitions along the batch axis, though no caller
+            # shards per-slot caches: the sharded dry run decodes with
+            # a scalar index)
             idx = cache["index"]
-            bsz, s_new = k.shape[0], k.shape[1]
-            s_max = cache["k"].shape[1]
+            s_new = k.shape[1]
             if idx.ndim == 0:
                 ck = jax.lax.dynamic_update_slice_in_dim(
                     cache["k"], k.astype(cache["k"].dtype), idx, axis=1)
                 cv = jax.lax.dynamic_update_slice_in_dim(
                     cache["v"], v.astype(cache["v"].dtype), idx, axis=1)
             elif s_new == 1:
-                sel = (jnp.arange(s_max)[None, :]
-                       == idx[:, None])[..., None, None]
-                ck = jnp.where(sel, k.astype(cache["k"].dtype),
-                               cache["k"])
-                cv = jnp.where(sel, v.astype(cache["v"].dtype),
-                               cache["v"])
+                ck = write_rows(cache["k"], k, idx)
+                cv = write_rows(cache["v"], v, idx)
             else:
                 # a multi-token write (prefill, or one chunk of it):
                 # the rows start together, at the first row's index

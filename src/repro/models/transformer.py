@@ -205,10 +205,16 @@ def forward(params, x, cfg: ArchConfig, *, positions, caches=None,
             return x  # no mesh / axis missing: constraint is a no-op
 
     def body(carry, xs):
-        x = carry
+        # the caches ride in the carry: each period reads its slice and
+        # writes it back, so a step updates the stacked buffers in place
+        # (as scan xs → ys they would be rebuilt whole every call); what
+        # a layer adds to its cache (cross-KV at prefill) goes out as ys
+        x, cs = carry
         x = seq_constraint(x)
-        bp = xs[0]
-        cc = xs[1] if caches is not None else [None] * cfg.period
+        bp, j = xs
+        cc = ([jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                   a, j, keepdims=False), c) for c in cs]
+              if cs is not None else [None] * cfg.period)
         new_cc, per_layer = [], []
         for i in range(cfg.period):
             x, nc, st = _apply_layer(bp[i], x, cfg, i, positions=positions,
@@ -218,10 +224,17 @@ def forward(params, x, cfg: ArchConfig, *, positions, caches=None,
             if st is not None:
                 per_layer.append(st)
         x = seq_constraint(x)
-        ys = new_cc if caches is not None else None
+        ys = None
+        if cs is not None:
+            cs = [{key: jax.tree.map(
+                      lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                          a, n, j, 0), c[key], nc[key])
+                   for key in c} for c, nc in zip(cs, new_cc)]
+            ys = [{key: val for key, val in nc.items() if key not in c}
+                  for c, nc in zip(cc, new_cc)]
         if with_stats:
-            return x, (ys, _sum_stats(per_layer))
-        return x, ys
+            return (x, cs), (ys, _sum_stats(per_layer))
+        return (x, cs), ys
 
     if cfg.remat == "full":
         body = jax.checkpoint(body)
@@ -231,17 +244,19 @@ def forward(params, x, cfg: ArchConfig, *, positions, caches=None,
         body = jax.checkpoint(
             body,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    xs = (blocks,) if caches is None else (blocks, caches)
-    x, ys = jax.lax.scan(body, x, xs)
+    (x, caches), ys = jax.lax.scan(
+        body, (x, caches), (blocks, jnp.arange(cfg.n_periods)))
+    added, stats = ys if with_stats else (ys, None)
+    if caches is not None:
+        caches = [{**c, **a} for c, a in zip(caches, added)]
     if not with_stats:
-        return x, ys
-    new_caches, stats = ys
+        return x, caches
     if stats:
         counts = stats["expert_counts"]
         stats = {"expert_counts": counts.reshape((-1,) + counts.shape[2:]),
                  **{k: v.sum() for k, v in stats.items()
                     if k != "expert_counts"}}
-    return x, new_caches, stats
+    return x, caches, stats
 
 
 def encode(params, src_embeds, cfg: ArchConfig):

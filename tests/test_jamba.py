@@ -23,7 +23,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.models import moe
 from repro.models import reference_jamba as R
 from repro.models import transformer as T
-from repro.serve.engine import Request, ServeEngine
+from repro.serve.engine import Request, ServeEngine, lm_steps
 
 
 def _cfg(dtype=jnp.float32, **kw):
@@ -199,3 +199,70 @@ def test_launch_serve_takes_the_cut():
                   "0:4", "--vocab-slice", "128", "--requests", "2",
                   "--slots", "2", "--max-tokens", "3"])
     assert stats["requests"] == 2
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_per_slot_kv_write_matches_one_hot_select(dtype):
+    """The per-slot decode writes each row's one position as a one-hot
+    select over the whole cache would, bit for bit, on K and V: rows at
+    position 0, in the middle and at ``max_seq - 1``; a row with valid
+    0 writes and does not advance; an index at ``max_seq`` writes
+    nothing."""
+    from repro.models.common import apply_attn, init_attn, rmsnorm
+
+    cfg = _cfg(dtype)
+    s_max = 32
+    idx = jnp.asarray([0, 13, s_max - 1, 7, s_max], jnp.int32)
+    valid = jnp.asarray([1, 1, 1, 0, 1], jnp.int32)
+    b = idx.shape[0]
+    p, _ = init_attn(T.KeyGen(jax.random.key(7)), cfg)
+    keys = jax.random.split(jax.random.key(8), 3)
+    x = jax.random.normal(keys[0], (b, 1, cfg.d_model), dtype)
+    shape = (b, s_max, cfg.kv_heads, cfg.head_dim)
+    cache = {"k": jax.random.normal(keys[1], shape, dtype),
+             "v": jax.random.normal(keys[2], shape, dtype),
+             "index": idx}
+    _, got = apply_attn(p, x, cfg, positions=idx[:, None],
+                        cache=dict(cache), valid=valid)
+    h = rmsnorm(x, p["ln"], cfg.norm_eps)
+    sel = (jnp.arange(s_max)[None, :] == idx[:, None])[..., None, None]
+    for name in ("k", "v"):
+        new = jnp.einsum("bsd,dhk->bshk", h, p["w" + name])
+        want = jnp.where(sel, new.astype(dtype), cache[name])
+        bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+        assert np.array_equal(np.asarray(got[name]).view(bits),
+                              np.asarray(want).view(bits)), name
+        assert not np.array_equal(np.asarray(got[name][:3]),
+                                  np.asarray(cache[name][:3])), name
+        assert np.array_equal(np.asarray(got[name][4]),
+                              np.asarray(cache[name][4])), name
+    assert list(np.asarray(got["index"])) == [1, 14, s_max, 7, s_max + 1]
+
+
+@pytest.mark.parametrize("periods", [1, 2])
+def test_decode_temporaries_do_not_scale_with_the_cache(periods):
+    """The compiled batched decode (16 slots, caches donated) writes
+    its K and V in place: doubling ``max_seq`` from 1024 to 2048 grows
+    its temporaries by under a tenth of one K cache's growth with one
+    period. With two, each period's K and V slice is copied out of the
+    stacked caches and back, two K caches' growth; a select over the
+    caches and stacking them as the layer scan's outputs grew them by
+    six."""
+    base = get_smoke_config("jamba2_mini")
+    cfg = dataclasses.replace(base, dtype=jnp.float32,
+                              layers=periods * base.period)
+    slots = 16
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.key(0))[0])
+    toks = jax.ShapeDtypeStruct((slots, 1), jnp.int32)
+    valid = jax.ShapeDtypeStruct((slots,), jnp.int32)
+
+    def temp(max_seq):
+        caches = jax.eval_shape(lambda: T.init_caches(cfg, slots, max_seq))
+        step = lm_steps(cfg, max_seq).decode
+        compiled = step.lower(params, toks, valid, caches).compile()
+        return compiled.memory_analysis().temp_size_in_bytes
+
+    k_growth = slots * 1024 * cfg.kv_heads * cfg.head_dim * 4
+    growth = temp(2048) - temp(1024)
+    assert growth <= (0.1 if periods == 1 else 3) * k_growth, growth
