@@ -1,19 +1,11 @@
-"""STAP radar benchmark — reproduces the paper's §5.3 methodology
-(Figs. 9–10) at container scale.
+"""STAP radar benchmark — the paper's §5.3 pipeline at container scale.
 
 Pipeline per data cube (paper Fig. 7): beamforming (steer-vector ×
-channels matmul) → Doppler FFT → match-filter multiply. Variants:
-
-  python_numpy   — original sequential NumPy implementation;
-  automphc       — the compiler's auto-parallelized version: the cube loop
-                   is detected as pfor, tiled, and distributed as raylite
-                   tasks (the Ray deployment of §4.3);
-  projection     — multi-node throughput projected from the measured
-                   single-worker per-cube time and the measured raylite
-                   scheduling overhead, for the paper's node counts.
-                   (This container has one CPU core: real multi-node
-                   scaling cannot be measured, so the cluster dimension is
-                   SIMULATED and labeled as such — see EXPERIMENTS.md.)
+channels matmul) → Doppler FFT → match-filter multiply. ``run()`` times
+the original sequential NumPy implementation; ``--distrib``,
+``--hetero``, ``--pallas`` and ``--chaos`` run the compiler's
+auto-parallelized version (the cube loop detected as pfor and sharded
+over :class:`repro.distrib.ClusterRuntime` worker processes).
 
 Reported metric: cubes/sec (the paper's real-time requirement is 33.3
 cubes/sec at full problem size; we also report our scaled-size numbers
@@ -71,9 +63,6 @@ def make_data(n_cubes=N_CUBES, seed=5):
 
 
 def run(csv: bool = True) -> List[Dict]:
-    from repro.core.compiler import compile_kernel
-    from repro.runtime import TaskRuntime
-
     cubes, sv, mf, out = make_data()
     rows = []
 
@@ -82,46 +71,13 @@ def run(csv: bool = True) -> List[Dict]:
     t0 = time.perf_counter()
     stap_ref(cubes, sv, mf, out_ref, N_CUBES, FFT_SIZE)
     t_seq = time.perf_counter() - t0
-    seq_tput = N_CUBES / t_seq
     rows.append({"variant": "python_numpy", "workers": 1,
-                 "cubes_per_s": seq_tput, "measured": True})
-
-    # -- AutoMPHC + raylite -------------------------------------------------
-    for workers in (1, 2, 4):
-        rt = TaskRuntime(workers=workers, speculation=False)
-        ck = compile_kernel(stap_kernel, runtime=rt, workers=workers)
-        ck.pfor_config.distribute_threshold = 0  # force distribution
-        out_a = out.copy()
-        ck.call_variant("np", cubes, sv, mf, out_a, N_CUBES, FFT_SIZE)
-        t0 = time.perf_counter()
-        out_a = out.copy()
-        ck.call_variant("np", cubes, sv, mf, out_a, N_CUBES, FFT_SIZE)
-        t_am = time.perf_counter() - t0
-        assert np.allclose(out_a, out_ref), "automphc STAP mismatch"
-        rows.append({"variant": "automphc_raylite", "workers": workers,
-                     "cubes_per_s": N_CUBES / t_am, "measured": True,
-                     "stats": rt.stats()})
-        rt.shutdown()
-
-    # -- projected multi-node scaling (SIMULATED — 1 physical core) -------
-    t_cube = 1.0 / max(r["cubes_per_s"] for r in rows
-                       if r["measured"])
-    t_sched = 0.0008  # measured raylite submit+get overhead per task
-    for nodes in (1, 2, 4, 8, 16, 24):
-        workers = nodes * 6  # paper: 6 GPUs/node on Summit
-        per_node = N_CUBES / max(1, workers)
-        t_total = per_node * t_cube + t_sched * N_CUBES / workers \
-            + 0.002 * nodes  # inter-node result gather
-        rows.append({"variant": "projected_multinode", "workers": workers,
-                     "nodes": nodes,
-                     "cubes_per_s": N_CUBES / t_total,
-                     "measured": False})
+                 "cubes_per_s": N_CUBES / t_seq})
 
     if csv:
         for r in rows:
-            tag = "" if r["measured"] else " (projected)"
             print(f"stap.{r['variant']},workers={r['workers']},"
-                  f"{r['cubes_per_s']:.2f}_cubes_per_s{tag}", flush=True)
+                  f"{r['cubes_per_s']:.2f}_cubes_per_s", flush=True)
         scale = PAPER_CUBE_FLOPS / OUR_CUBE_FLOPS
         print(f"stap.scale_note,paper_cube/our_cube_flops={scale:.0f}x,"
               f"realtime_req_scaled={33.3 / 1:.1f}_cubes_per_s_at_full_size")

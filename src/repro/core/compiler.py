@@ -43,7 +43,7 @@ from repro.profiler.tracer import FunctionTrace, Tracer
 
 from . import backends, codegen, cost, parser, schedule as schedule_mod, scop
 from .multiversion import CompiledKernel, Variant
-from .pfor import PforConfig
+from .pfor import PforConfig, check_runtime
 
 
 def _stage(scope, kernel: str, name: str, t0: float, t1: float,
@@ -151,6 +151,7 @@ def compile_kernel(
     kname = getattr(fn, "__name__", "kernel")
     cscope = obs.metrics.scope(f"compile.{kname}")
 
+    check_runtime(runtime)
     pfor_cfg = PforConfig(runtime=runtime, tile=tile, workers=workers)
     pfor_cfg.distribute_threshold = cost.DISTRIBUTE_FLOP_THRESHOLD
 
@@ -208,7 +209,6 @@ def compile_kernel(
     # the cluster runtime diffs only schedule-written arrays when
     # gathering pfor chunk results from worker processes
     pfor_cfg.written = tuple(sched.written)
-    pfor_cfg.sliceable = _sliceable_union(sched)
 
     variants: Dict[str, Variant] = {
         "original": Variant("original", fn),
@@ -267,23 +267,12 @@ def compile_kernel(
     return ck
 
 
-def _sliceable_union(sched) -> tuple:
-    """Union of per-unit chunk-sliceable arrays (telemetry + fallback for
-    generated bodies predating the ``__sliceable__`` attribute)."""
-    names = {n
-             for u in schedule_mod._flatten(sched.units)
-             if isinstance(u, schedule_mod.PforUnit)
-             for n in getattr(u, "sliceable", ())}
-    return tuple(sorted(names))
-
-
 def _rebuild_from_entry(fn: Callable, entry: CacheEntry,
                         pfor_cfg: PforConfig,
                         accel_threshold: float) -> Optional[CompiledKernel]:
     """Warm start: dispatcher from stored source, no front-end work."""
     try:
         pfor_cfg.written = tuple(getattr(entry.sched, "written", ()) or ())
-        pfor_cfg.sliceable = _sliceable_union(entry.sched)
         variants: Dict[str, Variant] = {
             "original": Variant("original", fn),
         }

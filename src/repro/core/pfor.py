@@ -4,37 +4,66 @@ Generated kernels call ``__pfor_run(body, lo, hi, tile)`` where ``body(lo,
 hi)`` executes a contiguous chunk of dependence-free iterations, writing
 disjoint regions of the output arrays in place.
 
-Backends (a profitability decision, §4.3):
-  * sequential      — one call; chosen for small iteration counts;
-  * raylite DAG     — chunks submitted as tasks to the runtime/ package
-    (the Ray analogue): futures, lineage fault tolerance, straggler
-    duplicates all apply;
-  * cluster shards  — when the bound runtime is a
-    :class:`repro.distrib.ClusterRuntime` (it exposes ``pfor_shards``),
-    chunks cross OS-process boundaries: the body closure ships to worker
-    processes, chunk sizes follow measured device capability, and
-    disjoint-region writes gather back on the head. The local-vs-
-    distributed call is made per kernel from the fleet's device profiles
+Two outcomes (a profitability decision, §4.3):
+  * on the caller — one call of the body; chosen when no runtime is
+    bound, sequential execution is forced, the range is trivial, or the
+    runtime judges distribution unprofitable;
+  * cluster shards — the bound runtime (:class:`repro.distrib.
+    ClusterRuntime`) ships the body closure to worker processes, sizes
+    chunks by measured device capability, and gathers the disjoint-
+    region writes back on the head. The local-vs-distributed call is
+    made per kernel from the fleet's device profiles
     (:func:`repro.core.cost.cluster_distribute_profitable`).
 
 The SPMD (shard_map) mapping of regular pfor loops lives in the LM planner
-(core/planner.py) — numeric kernels distribute via the DAG or the cluster
-runtime, matching the paper's Ray deployment.
+(core/planner.py) — numeric kernels distribute via the cluster runtime,
+matching the paper's Ray deployment.
 """
 
 from __future__ import annotations
 
-import inspect
 import math
 from typing import Callable, Optional, Tuple
 
+# the calls a bound runtime must provide (checked by compile_kernel)
+RUNTIME_PROTOCOL = ("pfor_shards", "distribute_profitable")
+
+
+def check_runtime(runtime) -> None:
+    """Raise ``TypeError`` unless ``runtime`` is None or implements
+    :data:`RUNTIME_PROTOCOL`."""
+    if runtime is None:
+        return
+    missing = [m for m in RUNTIME_PROTOCOL
+               if not callable(getattr(runtime, m, None))]
+    if missing:
+        raise TypeError(
+            f"runtime {type(runtime).__name__} lacks "
+            f"{', '.join(missing)}: a pfor runtime provides "
+            f"{' and '.join(RUNTIME_PROTOCOL)}")
+
 
 class PforConfig:
-    """Mutable knob block bound into each compiled kernel."""
+    """Mutable knob block bound into each compiled kernel.
+
+    A bound ``runtime`` provides exactly two calls:
+
+    * ``distribute_profitable(flops, bcast_bytes, n_chunks, *,
+      sliced_bytes)`` — whether shipping the loop beats running it on
+      the caller; ``bcast_bytes`` ride to every worker, ``sliced_bytes``
+      ship once in total, split by row;
+    * ``pfor_shards(body, lo, hi, tile, *, written, sliceable,
+      est_flops)`` — run ``body`` over ``[lo, hi)`` in chunks and merge
+      their writes to the ``written`` arrays into the caller's arrays;
+      ``sliceable`` names the arrays that may ship as per-chunk row
+      slices, ``est_flops`` is the kernel's FLOP estimate.
+
+    ``distribute_threshold <= 0`` skips the profitability question and
+    always distributes."""
 
     def __init__(self, runtime=None, tile: Optional[int] = None,
                  workers: int = 4, force_sequential: bool = False):
-        self.runtime = runtime          # TaskRuntime | ClusterRuntime | None
+        self.runtime = runtime          # ClusterRuntime | None
         self.tile = tile
         self.workers = workers
         self.force_sequential = force_sequential
@@ -44,113 +73,37 @@ class PforConfig:
         # arrays the schedule writes (set by the compiler) — lets the
         # cluster runtime diff only real outputs when gathering chunks
         self.written: Tuple[str, ...] = ()
-        # arrays provably indexed only by the pfor var on their leading
-        # axis (union over the kernel's pfor units, set by the compiler).
-        # Fallback only: freshly generated bodies carry their own exact
-        # per-unit ``__sliceable__`` attribute, which always wins; this
-        # covers variants cached before the attribute existed (their
-        # schedules predate the analysis too, so it stays empty — safe).
-        self.sliceable: Tuple[str, ...] = ()
-        # memoized signature probes for the bound runtime (legacy duck-
-        # typed runtimes may predate the broadcast/sliced protocol):
-        # (runtime object, decide accepts sliced_bytes, shards accepts
-        # sliceable, shards accepts est_flops) — re-probed only when the
-        # runtime is swapped. The memo holds the probed object itself,
-        # never a raw id(): address reuse after a swap must not
-        # resurrect a stale verdict.
-        self._proto_probe: Tuple[object, bool, bool, bool] = (
-            None, True, True, True)
-
-    def _runtime_proto(self, shards) -> Tuple[bool, bool, bool]:
-        """(decide takes sliced_bytes, pfor_shards takes sliceable,
-        pfor_shards takes est_flops) for the current runtime, probed
-        once per binding — not per call."""
-        if self._proto_probe[0] is not self.runtime:
-            def accepts(fn, kw):
-                if fn is None:
-                    return True
-                try:
-                    return kw in inspect.signature(fn).parameters
-                except (TypeError, ValueError):
-                    return True
-            decide = getattr(self.runtime, "distribute_profitable", None)
-            self._proto_probe = (self.runtime,
-                                 accepts(decide, "sliced_bytes"),
-                                 accepts(shards, "sliceable"),
-                                 accepts(shards, "est_flops"))
-        return self._proto_probe[1:]
 
     def make_runner(self) -> Callable:
         def __pfor_run(body, lo, hi, tile):
             n = max(0, hi - lo)
             if n == 0:
                 return
-            tile_ = tile or self.tile
-            if tile_ is None:
-                tile_ = max(1, math.ceil(n / max(1, self.workers)))
             if self.force_sequential or self.runtime is None or n <= 1:
                 body(lo, hi)
                 return
-            shards = getattr(self.runtime, "pfor_shards", None)
-            if shards is not None:
-                # a cluster runtime instance exists, so repro.distrib is
-                # already imported — the shared sizing rule is free here
+            # codegen stamps every pfor body with the arrays its unit
+            # proves chunk-sliceable (possibly empty)
+            sliceable = tuple(getattr(body, "__sliceable__", ()))
+            if self.distribute_threshold > 0:
+                # the cluster's own payload sizing rule (imported here:
+                # the caller-only path never loads repro.distrib)
                 from repro.distrib.serial import payload_split_nbytes
 
-                sliceable = getattr(body, "__sliceable__", None)
-                if sliceable is None:
-                    sliceable = self.sliceable
-                # legacy duck-typed runtimes may predate the broadcast/
-                # sliced protocol: signature-probe once per runtime
-                # binding rather than catching TypeError per call (which
-                # would also swallow genuine errors inside the model)
-                split_ok, shards_sliceable, shards_flops = \
-                    self._runtime_proto(shards)
-                sliceable = tuple(sliceable) if shards_sliceable else ()
-                # cluster tier: ask the device-profile cost model unless
-                # the caller forced distribution (threshold <= 0)
-                distribute = self.distribute_threshold <= 0
-                if not distribute:
-                    decide = getattr(self.runtime,
-                                     "distribute_profitable", None)
-                    if decide is not None:
-                        bcast, sliced = payload_split_nbytes(
-                            body, sliceable)
-                        if split_ok:
-                            distribute = decide(
-                                self.estimated_flops, bcast,
-                                max(1, math.ceil(n / tile_)),
-                                sliced_bytes=sliced)
-                        else:
-                            distribute = decide(
-                                self.estimated_flops, bcast + sliced,
-                                max(1, math.ceil(n / tile_)))
-                    else:
-                        distribute = (self.estimated_flops
-                                      >= self.distribute_threshold)
-                if distribute:
-                    kw = {"written": self.written}
-                    if shards_sliceable:
-                        kw["sliceable"] = sliceable
-                    if shards_flops:
-                        # the dispatcher's kernel-level FLOP estimate:
-                        # the sharder prices per-(unit, backend, worker)
-                        # cells from it when the body carries a jnp twin
-                        kw["est_flops"] = self.estimated_flops
-                    shards(body, lo, hi, tile or self.tile, **kw)
-                else:
+                tile_ = tile or self.tile
+                if tile_ is None:
+                    tile_ = max(1, math.ceil(n / max(1, self.workers)))
+                bcast, sliced = payload_split_nbytes(body, sliceable)
+                if not self.runtime.distribute_profitable(
+                        self.estimated_flops, bcast,
+                        max(1, math.ceil(n / tile_)),
+                        sliced_bytes=sliced):
                     body(lo, hi)
-                return
-            if self.estimated_flops < self.distribute_threshold:
-                body(lo, hi)
-                return
-            futures = []
-            t = lo
-            while t < hi:
-                up = min(t + tile_, hi)
-                futures.append(self.runtime.submit(body, t, up))
-                t = up
-            for f in futures:
-                self.runtime.get(f)
+                    return
+            # est_flops is the dispatcher's kernel-level estimate: the
+            # sharder prices per-(unit, backend, worker) cells from it
+            self.runtime.pfor_shards(
+                body, lo, hi, tile or self.tile, written=self.written,
+                sliceable=sliceable, est_flops=self.estimated_flops)
 
         return __pfor_run

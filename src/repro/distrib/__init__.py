@@ -1,7 +1,6 @@
 """Multi-process cluster runtime (the paper's Ray deployment tier, §4.3).
 
-raylite (:mod:`repro.runtime`) models a cluster with threads inside one
-process; this package crosses real OS-process boundaries:
+The project's one task runtime; it crosses real OS-process boundaries:
 
   * a **head** scheduler (:class:`ClusterRuntime`) spawns worker
     *processes* and talks to them over pipes or — with
@@ -19,7 +18,8 @@ process; this package crosses real OS-process boundaries:
   * ``pfor`` loops compiled by :func:`repro.core.compiler.optimize`
     shard dependence-free chunks across workers — chunk sizes
     proportional to measured capability — with disjoint-region writes
-    gathered on the head.
+    gathered on the head;
+  * :class:`ElasticController` resizes the fleet from queue depth.
 
     from repro.distrib import ClusterRuntime
     rt = ClusterRuntime(workers=4)
@@ -31,6 +31,7 @@ process; this package crosses real OS-process boundaries:
 from .chaos import ChaosPlan, ChaosWire
 from .cluster import ClusterRuntime, ClusterTaskError
 from .device import DeviceProfile, measure_profile
+from .elastic import ElasticController, ElasticPolicy
 from .objects import ClusterRef, ObjectMeta, ObjectPlane, TaskSpec
 from .placement import PlacementScheduler, PlacementWeights, WorkerView
 from .serial import (ChunkSlice, ClosureParts, assemble_fn, dumps_fn,
@@ -42,6 +43,7 @@ from .transport import (HeadListener, PipeLink, ReconnectingClient,
 __all__ = [
     "ChaosPlan", "ChaosWire", "ChunkSlice", "ClosureParts",
     "ClusterRuntime", "ClusterTaskError", "ClusterRef", "DeviceProfile",
+    "ElasticController", "ElasticPolicy",
     "HeadListener", "ObjectMeta", "ObjectPlane", "PipeLink",
     "PlacementScheduler", "PlacementWeights", "ReconnectingClient",
     "TaskSpec", "WorkerFencedError", "WorkerView", "assemble_fn",

@@ -1,10 +1,9 @@
 """ClusterRuntime: head scheduler over spawned worker processes.
 
-The multi-process generalization of :class:`repro.runtime.tasks.
-TaskRuntime` (raylite). Same duck-typed surface the compiled kernels
-use — ``submit`` / ``get`` / ``wait`` / ``stats`` — plus the
-``pfor_shards`` protocol :mod:`repro.core.pfor` dispatches to when its
-runtime crosses process boundaries:
+The project's one task runtime: DAG tasks through ``submit`` / ``get``
+/ ``wait`` / ``stats``, and the two calls :mod:`repro.core.pfor`
+dispatches compiled pfor loops to (``distribute_profitable`` and
+``pfor_shards``, stated on :class:`repro.core.pfor.PforConfig`):
 
   * workers are real OS processes (``multiprocessing`` transport, fork
     or spawn), each reporting a measured :class:`DeviceProfile`; the
@@ -2021,8 +2020,7 @@ class ClusterRuntime:
         return key
 
     def queue_depth(self) -> int:
-        """Unfinished tasks (duck-typed parity with TaskRuntime's pool
-        depth — what the elastic controller scales on)."""
+        """Unfinished tasks (what the elastic controller scales on)."""
         with self._lock:
             return sum(1 for t in self._tasks.values() if not t.finished)
 

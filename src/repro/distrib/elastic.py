@@ -1,19 +1,14 @@
-"""Elastic autoscaling controller for the task runtimes.
+"""Elastic autoscaling controller for the cluster runtime.
 
-Watches queue depth and resizes the fleet within
-[min_workers, max_workers]. The same control loop drives both runtime
-flavors, duck-typed on ``scale_to`` plus a size/depth probe:
-
-  * :class:`repro.runtime.tasks.TaskRuntime` — thread-backed pool
-    (``rt.pool.size`` / ``rt.pool.queue_depth()``); scaling is instant.
-  * :class:`repro.distrib.cluster.ClusterRuntime` — real worker
-    processes (``rt.workers_alive()`` / ``rt.queue_depth()``); growth
-    spawns + profiles + pre-warms a worker, shrink marks one draining
-    (it finishes in-flight work, hands objects back, then exits).
+Watches queue depth and resizes the fleet of
+:class:`repro.distrib.ClusterRuntime` within [min_workers, max_workers]
+through ``rt.scale_to``: growth spawns + profiles + pre-warms a worker,
+shrink marks one draining (it finishes in-flight work, hands objects
+back, then exits).
 
 On real clusters this is the autoscaler requesting/releasing nodes;
 here it exercises the same control loop against live fleets so
-elasticity is a tested property of the runtimes, not an aspiration.
+elasticity is a tested property of the runtime, not an aspiration.
 """
 
 from __future__ import annotations
@@ -33,12 +28,12 @@ class ElasticPolicy:
 
 
 class ElasticController:
-    """Queue-depth autoscaler over any runtime exposing ``scale_to``.
+    """Queue-depth autoscaler over a runtime exposing ``scale_to``.
 
-    Size and depth are probed duck-typed: a thread-pool runtime exposes
-    them on ``rt.pool``, the cluster runtime directly (a draining
-    cluster worker no longer counts toward size, so the controller
-    never double-shrinks a drain already in progress)."""
+    Size counts live, non-draining workers (a draining worker no longer
+    counts, so the controller never double-shrinks a drain already in
+    progress); depth is the runtime's ``queue_depth()`` unless
+    ``depth_fn`` overrides it."""
 
     def __init__(self, rt, policy: ElasticPolicy = None,
                  interval_s: float = 0.05,
@@ -56,9 +51,6 @@ class ElasticController:
         self.decisions: list = []
 
     def _size(self) -> int:
-        pool = getattr(self.rt, "pool", None)
-        if pool is not None:
-            return int(pool.size)
         views = getattr(self.rt, "_views", None)
         if views is not None:
             # live, non-draining, attached workers — what placement
@@ -69,9 +61,6 @@ class ElasticController:
     def _depth(self) -> int:
         if self.depth_fn is not None:
             return int(self.depth_fn())
-        pool = getattr(self.rt, "pool", None)
-        if pool is not None:
-            return int(pool.queue_depth())
         return int(self.rt.queue_depth())
 
     def tick(self) -> int:
@@ -95,7 +84,7 @@ class ElasticController:
                 self.tick()
 
         self._thread = threading.Thread(target=loop, daemon=True,
-                                        name="raylite-elastic")
+                                        name="elastic")
         self._thread.start()
 
     def stop(self) -> None:

@@ -4,9 +4,7 @@ Objects produced by cluster tasks live **where they were produced** (the
 owning worker's in-process cache); the head keeps only a directory entry
 (owner, size) unless the value was small enough to inline. ``get`` pulls
 on demand; a dead owner turns the entry LOST and the lineage record —
-the serialized task spec — is replayed on a surviving worker, exactly
-the recovery contract :mod:`repro.runtime.lineage` implements inside one
-process.
+the serialized task spec — is replayed on a surviving worker.
 """
 
 from __future__ import annotations

@@ -23,7 +23,7 @@ Two engines share one front-door discipline (admission → bounded queue
   and every accepted request still gets the exact tokens it would have
   gotten — bitwise equal to the single-process engine.
 
-Queue depth is exported for :class:`repro.runtime.elastic.ElasticController`
+Queue depth is exported for :class:`repro.distrib.ElasticController`
 (``depth_fn=engine.queue_depth``), closing the loop: load → queue →
 fleet size.
 """

@@ -218,6 +218,40 @@ def test_upstream_error_poisons_dependents(cluster):
         cluster.get(b, timeout=60)
 
 
+def _fail_first_attempt(marker, x):
+    # the task runs in a worker process: the marker file is the only
+    # state its attempts share
+    if not os.path.exists(marker):
+        open(marker, "w").close()
+        raise RuntimeError("first attempt fails")
+    return x * 2
+
+
+def test_task_retry_succeeds_after_first_attempt_fails(cluster, tmp_path):
+    assert cluster.max_attempts >= 2
+    marker = str(tmp_path / "attempted")
+    before = cluster.stats()["resubmits"]
+    ref = cluster.submit(_fail_first_attempt, marker, 21)
+    assert cluster.get(ref, timeout=30) == 42
+    assert os.path.exists(marker)
+    assert cluster.stats()["resubmits"] == before + 1
+
+
+def _sleep_then(seconds, x):
+    time.sleep(seconds)
+    return x
+
+
+def test_submit_returns_before_task_finishes(cluster):
+    t0 = time.perf_counter()
+    ref = cluster.submit(_sleep_then, 1.0, 7)
+    assert time.perf_counter() - t0 < 0.5   # submission does not block
+    ready, pending = cluster.wait([ref], num_returns=1, timeout=0.0)
+    assert ready == [] and pending == [ref]
+    assert cluster.get(ref, timeout=30) == 7
+    assert time.perf_counter() - t0 >= 1.0
+
+
 def test_cluster_large_result_stays_remote_until_get(cluster):
     ref = cluster.submit(_make, 200_000)
     cluster.wait([ref], num_returns=1, timeout=30)
@@ -298,6 +332,33 @@ def test_worker_kill_lineage_replay():
         assert np.array_equal(v, np.arange(300_000.0))
         assert rt.stats()["lineage_replays"] >= 1
         assert rt.stats()["worker_deaths"] == 1
+    finally:
+        rt.shutdown()
+
+
+def _plus_one(a):
+    return a + 1.0
+
+
+def test_worker_kill_replays_a_chain_of_three():
+    """A one-worker fleet loses every object of a three-task chain with
+    its worker: reading the tip replays the whole chain, transitively,
+    on the respawned worker."""
+    rt = ClusterRuntime(workers=1)
+    try:
+        a = rt.submit(_make, 300_000)
+        b = rt.submit(_plus_one, a)
+        c = rt.submit(_plus_one, b)
+        rt.wait([a, b, c], num_returns=3, timeout=30)
+        (owner,) = {rt.plane.meta(r.oid).owner for r in (a, b, c)}
+        assert all(rt.plane.meta(r.oid).state == "remote"
+                   for r in (a, b, c))
+        rt.kill_worker(owner)
+        v = rt.get(c, timeout=60)
+        assert np.array_equal(v, np.arange(300_000.0) + 2.0)
+        st = rt.stats()
+        assert st["worker_deaths"] == 1
+        assert st["lineage_replays"] >= 3
     finally:
         rt.shutdown()
 

@@ -17,11 +17,10 @@ import numpy as np
 import pytest
 
 from repro.distrib import (ChaosPlan, ChaosWire, ClusterRuntime,
-                           new_authkey)
+                           ElasticController, ElasticPolicy, new_authkey)
 from repro.distrib import chaos
 from repro.distrib.transport import (AuthenticationError,
                                      authed_connect)
-from repro.runtime import ElasticController, ElasticPolicy
 
 
 def _nap(seconds):
@@ -335,3 +334,30 @@ def test_elastic_controller_drives_cluster_runtime():
                 break
             time.sleep(0.05)
         _poll(lambda: rt.workers_alive() == 1, desc="scale back down")
+
+
+def _busy_worker(rt):
+    with rt._lock:
+        busy = [t.wid for t in rt._tasks.values()
+                if not t.finished and t.dispatched_at is not None]
+    return busy[0] if busy else None
+
+
+def test_elastic_controller_sizes_from_live_workers_while_one_drains():
+    with ClusterRuntime(workers=3) as rt:
+        ref = rt.submit(_nap, 2.0)
+        _poll(lambda: _busy_worker(rt) is not None, desc="task dispatched")
+        wid = _busy_worker(rt)
+        assert rt.drain_worker(wid) == wid
+        # the draining worker is still alive (finishing its task) but
+        # no longer counts: at min_workers the controller holds steady
+        # instead of shrinking a second worker
+        ctrl = ElasticController(rt, ElasticPolicy(min_workers=2,
+                                                   max_workers=4),
+                                 depth_fn=lambda: 0)
+        assert rt.workers_alive() == 3
+        assert ctrl.tick() == 2
+        assert ctrl.decisions == []
+        rt.get(ref, timeout=30.0)
+        _poll(lambda: rt.workers_alive() == 2, desc="drain completes")
+        assert rt.stats()["worker_deaths"] == 0

@@ -71,20 +71,20 @@ def run_sliced_inprocess(body, lo, hi, written, sliceable, n_chunks=3):
 
 
 class InProcessShards:
-    """Duck-typed runtime: PforConfig dispatches pfor units here, so a
-    compiled kernel exercises codegen's ``__sliceable__`` hand-off and
+    """In-process pfor runtime: PforConfig dispatches pfor units here, so
+    a compiled kernel exercises codegen's ``__sliceable__`` hand-off and
     the full slicing path synchronously in this process."""
 
     def __init__(self):
         self.calls = []
 
-    def pfor_shards(self, body, lo, hi, tile=None, written=(),
-                    sliceable=()):
+    def pfor_shards(self, body, lo, hi, tile, *, written, sliceable,
+                    est_flops):
         self.calls.append(tuple(sliceable))
         run_sliced_inprocess(body, lo, hi, written, sliceable)
 
-    def distribute_profitable(self, flops, payload_bytes, n_chunks,
-                              sliced_bytes=0.0):
+    def distribute_profitable(self, flops, payload_bytes, n_chunks, *,
+                              sliced_bytes):
         return True
 
 
@@ -356,8 +356,8 @@ def _equivalence_case(vec, dot, scal, oidx, n, t, seed):
     body_holder = {}
 
     class Bcast(InProcessShards):
-        def pfor_shards(self, body, lo, hi, tile=None, written=(),
-                        sliceable=()):
+        def pfor_shards(self, body, lo, hi, tile, *, written, sliceable,
+                        est_flops):
             run_sliced_inprocess(body, lo, hi, written, ())
 
     ck.pfor_config.runtime = Bcast()

@@ -9,8 +9,8 @@ import numpy as np
 import pytest
 
 from repro.core.compiler import compile_kernel
-from repro.distrib import ClusterRuntime
-from repro.runtime.elastic import ElasticController, ElasticPolicy
+from repro.distrib import (ClusterRuntime, ElasticController,
+                           ElasticPolicy)
 from repro.serve import (AdmissionController, AdmissionError, BatchSpec,
                          ClusterServeEngine, TenantQuota, open_loop)
 

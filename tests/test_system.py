@@ -154,23 +154,51 @@ def test_end_to_end_checkpoint_restart_resume():
 
 
 def test_end_to_end_stap_with_fault_injection():
-    """STAP pipeline distributed over raylite keeps producing correct
-    results while tasks fail and are retried."""
+    """STAP pipeline distributed over the cluster runtime keeps producing
+    correct results while a worker holding a chunk is SIGKILLed: the
+    head resubmits the lost chunk on the survivors."""
+    import threading
+    import time
+
     from benchmarks.stap import FFT_SIZE, make_data, stap_kernel, stap_ref
     from repro.core.compiler import compile_kernel
-    from repro.runtime import TaskRuntime
+    from repro.distrib import ClusterRuntime, chaos
 
     cubes, sv, mf, out = make_data(n_cubes=6)
     out_ref = out.copy()
     stap_ref(cubes, sv, mf, out_ref, 6, FFT_SIZE)
 
-    rt = TaskRuntime(workers=3, speculation=False)
-    try:
+    with ClusterRuntime(workers=2) as rt:
+        # every chunk on the original fleet stays in flight long enough
+        # for the kill to land on one (a respawned worker runs at speed)
+        for wid in list(rt._handles):
+            assert chaos.slow(rt, wid, per_task_s=0.5) == wid
         ck = compile_kernel(stap_kernel, runtime=rt, tile=2)
         ck.pfor_config.distribute_threshold = 0
+        killed = []
+
+        def kill_a_busy_worker():
+            deadline = time.monotonic() + 30.0
+            while time.monotonic() < deadline:
+                with rt._lock:
+                    # dispatched_at is stamped once the chunk is sent
+                    busy = [t.wid for t in rt._tasks.values()
+                            if not t.finished
+                            and t.dispatched_at is not None]
+                if busy:
+                    killed.append(rt.kill_worker(busy[0]))
+                    return
+                time.sleep(0.005)
+
+        killer = threading.Thread(target=kill_a_busy_worker)
+        killer.start()
         out_got = out.copy()
         ck.call_variant("np", cubes, sv, mf, out_got, 6, FFT_SIZE)
+        killer.join()
         np.testing.assert_allclose(out_got, out_ref, atol=1e-9)
-        assert rt.stats()["tasks"] >= 3
-    finally:
-        rt.shutdown()
+        assert killed and killed[0] is not None
+        st = rt.stats()
+        assert st["pfor_runs"] == 1
+        assert st["chunks_dispatched"] >= 3
+        assert st["worker_deaths"] == 1
+        assert st["resubmits"] >= 1
