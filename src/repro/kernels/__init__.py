@@ -1,4 +1,5 @@
-"""In-repo Pallas kernels (matmul, flash attention, selective scan).
+"""In-repo Pallas kernels (matmul, flash attention, selective scan,
+per-slot decode attention).
 
 Each kernel package holds the kernel, a jnp oracle (``ref.py``) and a
 dispatching wrapper (``ops.py``); :mod:`repro.kernels.api` is the chunk

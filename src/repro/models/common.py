@@ -482,8 +482,17 @@ def apply_attn(p, x, cfg: ArchConfig, *, positions, window=0, cache=None,
                     axis=1)
             step = s_new if valid is None else valid.astype(idx.dtype)
             cache = {"k": ck, "v": cv, "index": idx + step}
-            out = attention(q, ck, cv, cfg, causal=True, window=window,
-                            q_offset=idx)
+            from repro.kernels.decode_attention import ops as decode_ops
+
+            if idx.ndim == 1 and s_new == 1 and decode_ops.engaged(ck):
+                # one query a slot: read each slot's K and V only up to
+                # its length, in place (a row with valid 0 gets zeros)
+                out = decode_ops.decode_attention(
+                    q, ck, cv, idx, valid, window=window,
+                    softcap=cfg.attn_softcap)
+            else:
+                out = attention(q, ck, cv, cfg, causal=True,
+                                window=window, q_offset=idx)
         else:
             out = attention(q, k, v, cfg, causal=True, window=window)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])

@@ -24,6 +24,7 @@ import numpy as np
 
 from repro import obs
 
+from repro.kernels.decode_attention import ops as decode_ops
 from repro.models import transformer as T
 from repro.models.common import ArchConfig
 
@@ -141,7 +142,9 @@ class ServeEngine:
     ``moe.experts_hit`` (held experts that computed a token, summed over
     MoE layers and ticks) and ``moe.dropped``; ``cache.kv_bytes`` and
     ``cache.state_bytes`` sum, over ticks, the KV at the active slots'
-    lengths and their recurrent state.
+    lengths and their recurrent state; ``cache.kv_read_bytes`` the KV
+    the decode's attention read (``kv_read_positions`` of
+    ``repro.kernels.decode_attention.ops``).
 
     ``last_logits`` holds the last decode step's logits (B, Vpad) on
     the device, for the caller."""
@@ -180,10 +183,16 @@ class ServeEngine:
                                   "experts_hit", "dropped")}
         self._kv_bytes = self._mscope.counter("cache.kv_bytes")
         self._state_bytes = self._mscope.counter("cache.state_bytes")
-        # bytes a slot holds: KV per position, recurrent state in all
-        kv = [c["kv"] for c in self.caches if "kv" in c]
-        self._kv_per_token = sum(c["k"].nbytes + c["v"].nbytes
-                                 for c in kv) // (n_slots * max_seq)
+        self._kv_read_bytes = self._mscope.counter("cache.kv_read_bytes")
+        # bytes a slot holds: KV per position, recurrent state in all;
+        # each attention layer's window and KV bytes per position
+        self._kv_layers = [
+            (jax.ShapeDtypeStruct(c["kv"]["k"].shape, c["kv"]["k"].dtype),
+             cfg.layer_window(i),
+             (c["kv"]["k"].nbytes + c["kv"]["v"].nbytes)
+             // (n_slots * max_seq))
+            for i, c in enumerate(self.caches) if "kv" in c]
+        self._kv_per_token = sum(per_pos for _, _, per_pos in self._kv_layers)
         self._state_per_slot = sum(
             leaf.nbytes for c in self.caches if "ssm" in c
             for leaf in jax.tree.leaves(c["ssm"])) // n_slots
@@ -271,8 +280,12 @@ class ServeEngine:
         next_tokens, stats = jax.device_get((nxt, stats))
         clock.next("book")
         self._book_moe(stats, hits=True)
-        lengths = sum(int(self.slots.lengths[s]) for s in self.active)
-        self._kv_bytes.inc(lengths * self._kv_per_token)
+        lengths = [int(self.slots.lengths[s]) if s in self.active else 0
+                   for s in range(n_slots)]
+        self._kv_bytes.inc(sum(lengths) * self._kv_per_token)
+        self._kv_read_bytes.inc(sum(
+            per_pos * decode_ops.kv_read_positions(lengths, k, window)
+            for k, window, per_pos in self._kv_layers))
         self._state_bytes.inc(len(self.active) * self._state_per_slot)
         done_slots = []
         for slot, req in self.active.items():

@@ -266,3 +266,96 @@ def test_decode_temporaries_do_not_scale_with_the_cache(periods):
     k_growth = slots * 1024 * cfg.kv_heads * cfg.head_dim * 4
     growth = temp(2048) - temp(1024)
     assert growth <= (0.1 if periods == 1 else 3) * k_growth, growth
+
+
+def _engage_decode_kernel(monkeypatch, block):
+    """Route the per-slot decode's attention through the interpreted
+    kernel, in blocks of ``block`` positions of the f32 smoke caches."""
+    from repro.kernels.decode_attention import ops as decode_ops
+
+    cfg = _cfg()
+    monkeypatch.setattr(decode_ops, "engaged", lambda cache: True)
+    monkeypatch.setattr(decode_ops, "BLOCK_BYTES",
+                        block * cfg.kv_heads * cfg.head_dim * 4)
+
+
+def _serve(prompts, *, n_slots, max_seq, ticks):
+    cfg = _cfg()
+    params, _ = T.init_params(cfg, jax.random.key(2))
+    eng = ServeEngine(params, cfg, n_slots=n_slots, max_seq=max_seq,
+                      prefill_chunk=8)
+    reqs = [Request(f"r{i}", p, max_tokens=ticks + 2)
+            for i, p in enumerate(prompts)]
+    for r in reqs:
+        eng.add_request(r)
+    logits = []
+    for _ in range(ticks):
+        eng.step()
+        logits.append(np.asarray(eng.last_logits))
+    return eng, reqs, logits
+
+
+def test_engine_decodes_the_same_tokens_through_the_kernel(monkeypatch):
+    """ServeEngine with the per-slot decode's attention in the kernel
+    (blocks of 16, prompts across block edges, one slot idle) gives the
+    jnp path's tokens tick by tick, and its logits to float32 rounding;
+    the idle slot's logits are finite."""
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, _cfg().vocab, n) for n in (5, 15, 31)]
+    kw = dict(n_slots=4, max_seq=48, ticks=6)
+    _, want_reqs, want_logits = _serve(prompts, **kw)
+    _engage_decode_kernel(monkeypatch, 16)
+    _, got_reqs, got_logits = _serve(prompts, **kw)
+    assert [r.generated for r in got_reqs] == \
+        [r.generated for r in want_reqs]
+    for got, want in zip(got_logits, want_logits):
+        assert _err(got[:3], want[:3]) < 1e-5
+        assert np.all(np.isfinite(got))
+
+
+def test_kernel_decode_leaves_idle_slots_as_they_were(monkeypatch):
+    """Through the kernel, a row with valid 0 neither advances its KV
+    index nor moves its conv or recurrent state, and its logits are
+    finite (its attention reads nothing and gives zeros)."""
+    _engage_decode_kernel(monkeypatch, 16)
+    cfg = _cfg()
+    params, _ = T.init_params(cfg, jax.random.key(6))
+    caches = T.init_caches(cfg, 2, 40)
+    caches = jax.tree.map(lambda a: a + 1 if a.dtype != jnp.int32 else a,
+                          caches)
+    caches = [dict(c, kv=dict(c["kv"], index=jnp.asarray([[20, 33]],
+                                                          jnp.int32)))
+              if "kv" in c else c for c in caches]
+    tok = jnp.asarray([[3], [4]], jnp.int32)
+    logits, new = T.decode_step(params, tok, caches, cfg,
+                                valid=jnp.asarray([1, 0], jnp.int32))
+    assert np.all(np.isfinite(np.asarray(logits)))
+    for old_c, new_c in zip(caches, new):
+        if "ssm" in old_c:
+            for k in ("conv", "h"):
+                assert np.array_equal(old_c["ssm"][k][:, 1],
+                                      new_c["ssm"][k][:, 1])
+                assert not np.array_equal(old_c["ssm"][k][:, 0],
+                                          new_c["ssm"][k][:, 0])
+        else:
+            assert list(np.asarray(new_c["kv"]["index"][0])) == [21, 33]
+
+
+@pytest.mark.parametrize("engaged", [False, True], ids=["jnp", "kernel"])
+def test_kv_read_bytes_counts_what_the_attention_reads(monkeypatch,
+                                                       engaged):
+    """``cache.kv_read_bytes``: through the kernel, each slot's visible
+    length rounded up to the block (an idle slot fetches one block);
+    otherwise all ``max_seq`` positions of every slot."""
+    if engaged:
+        _engage_decode_kernel(monkeypatch, 16)
+    cfg = _cfg()
+    eng, _, _ = _serve([np.arange(5), np.arange(20)], n_slots=3,
+                       max_seq=48, ticks=2)
+    per_pos = 2 * cfg.kv_heads * cfg.head_dim * 4
+    # visible lengths: 6 and 21 in the first tick, 7 and 22 in the next
+    positions = (16 + 32 + 16) * 2 if engaged else 3 * 48 * 2
+    scope = eng._mscope
+    assert scope.counter("cache.kv_read_bytes").value == positions * per_pos
+    assert scope.counter("cache.kv_bytes").value == \
+        (6 + 21 + 7 + 22) * per_pos

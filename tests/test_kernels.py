@@ -167,3 +167,74 @@ def test_mamba_scan_chunking_invariance():
     o2 = mamba_scan(x, dt, Bm, Cm, a, d, chunk=16, force_pallas=True,
                     interpret=True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# decode attention
+# ---------------------------------------------------------------------------
+
+_DECODE_BLOCK = 16
+
+
+@pytest.mark.parametrize("seq", [64, 72], ids=["S64", "S72"])
+@pytest.mark.parametrize("softcap", [0.0, 50.0], ids=["cap0", "cap50"])
+@pytest.mark.parametrize("window", [0, 24, 5],
+                         ids=["nowin", "win_gt_block", "win_lt_block"])
+@pytest.mark.parametrize("groups", [1, 4], ids=["g1", "g4"])
+def test_decode_attention_matches_model_paths(monkeypatch, groups, window,
+                                              softcap, seq):
+    """The interpreted kernel, through its wrapper, against the model's
+    chunked and plain attention (and the kernel's oracle) for one query
+    a slot at visible lengths 1, block - 1, block, block + 1 and S, in
+    blocks of 16 positions; S = 72 leaves a part block at the end. A
+    row with valid 0 gives zeros."""
+    from repro.kernels.decode_attention import ops as decode_ops
+    from repro.kernels.decode_attention.ref import decode_attention_ref
+    from repro.models.common import chunked_attention, plain_attention
+
+    kvh, d = 2, 32
+    monkeypatch.setattr(decode_ops, "BLOCK_BYTES",
+                        _DECODE_BLOCK * kvh * d * 4)
+    blk = _DECODE_BLOCK
+    lengths = [1, blk - 1, blk, blk + 1, seq, 30]
+    valid = jnp.asarray([1, 1, 1, 1, 1, 0], jnp.int32)
+    idx = jnp.asarray(lengths, jnp.int32) - 1
+    b, h = len(lengths), kvh * groups
+    rng = np.random.default_rng(groups * 100 + window + seq)
+    q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(b, seq, kvh, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(b, seq, kvh, d)), jnp.float32)
+    assert decode_ops.block_size(k) == blk
+
+    got = np.asarray(decode_ops.decode_attention(
+        q, k, v, idx, valid, window=window, softcap=softcap))
+    kw = dict(causal=True, window=window, cap=softcap, q_offset=idx)
+    live = np.asarray(valid) == 1
+    for want in (chunked_attention(q, k, v, chunk=blk, **kw),
+                 plain_attention(q, k, v, **kw)):
+        np.testing.assert_allclose(got[live], np.asarray(want)[live],
+                                   atol=1e-5, rtol=1e-5)
+    n, first = decode_ops.visible_range(idx, valid, seq, window)
+    ref = decode_attention_ref(q[:, 0], k, v, n, first, softcap=softcap)
+    np.testing.assert_allclose(got[:, 0], np.asarray(ref), atol=1e-5,
+                               rtol=1e-5)
+    assert np.all(np.isfinite(got)) and not np.any(got[~live])
+
+
+def test_decode_kv_block_index_clamps_to_visible_blocks():
+    """The K/V index map as a plain function: steps past a slot's last
+    visible block map to that block, steps before its first (a window)
+    to the first, and an empty slot to block 0."""
+    from repro.kernels.decode_attention.decode_attention import \
+        kv_block_index
+
+    def blocks(first, n, steps=6, block=16):
+        return [int(kv_block_index(j, first, n, block))
+                for j in range(steps)]
+
+    assert blocks(0, 40) == [0, 1, 2, 2, 2, 2]
+    assert blocks(0, 32) == [0, 1, 1, 1, 1, 1]
+    assert blocks(0, 1) == [0] * 6
+    assert blocks(20, 40) == [1, 1, 2, 2, 2, 2]
+    assert blocks(35, 70) == [2, 2, 2, 3, 4, 4]
+    assert blocks(0, 0) == [0] * 6

@@ -187,3 +187,56 @@ def test_jamba_mamba_decode_compiles(one_chip):
     c = jax.jit(lambda p, x, st: ssm.apply_mamba(p, x, cfg, st)).lower(
         jax.tree.map(on, p_shapes), x, jax.tree.map(on, st_shapes)).compile()
     assert c.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+@pytest.mark.parametrize("arch", ["jamba2_mini", "olmoe_1b_7b",
+                                  "qwen1_5_110b", "llava_next_mistral_7b"])
+def test_decode_attention_compiles(one_chip, monkeypatch, arch):
+    """The per-slot decode attention through the kernel, at an arch's
+    published heads, 16 slots of 9216 positions in bf16 (for
+    jamba2_mini the benchmark cell's widths): the kernel is there, and
+    it reads the caches as they lie (no temporaries a cache's size)."""
+    from repro.configs import get_config
+    from repro.kernels.decode_attention import ops as decode_ops
+
+    monkeypatch.setattr(decode_ops, "interpret_mode", lambda: False)
+    cfg = get_config(arch)
+    b, s, bf16 = 16, 9216, jnp.bfloat16
+    kv = ((b, s, cfg.kv_heads, cfg.head_dim), bf16)
+    assert decode_ops.engaged(jax.ShapeDtypeStruct(*kv))
+    window = cfg.layer_window(cfg.attn_idx)
+    c = _compile(lambda q, k, v, idx, valid: decode_ops.decode_attention(
+        q, k, v, idx, valid, window=window, softcap=cfg.attn_softcap),
+        ((b, 1, cfg.n_heads, cfg.head_dim), bf16), kv, kv,
+        ((b,), jnp.int32), ((b,), jnp.int32), sharding=one_chip)
+    assert _has_kernel(c)
+    assert c.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def test_jamba_decode_step_reads_kv_through_the_kernel(one_chip,
+                                                       monkeypatch):
+    """The benchmark cell's decode step, one period of Jamba2-Mini at
+    published widths over 16 slots of 9216 positions, caches donated:
+    its attention is the kernel, not a loop over 1024-position slices
+    of the whole K and V caches, and its temporaries stay small."""
+    from repro.configs.jamba2_mini import ep_config
+    from repro.kernels.decode_attention import ops as decode_ops
+    from repro.models import transformer as T
+    from repro.serve.engine import lm_steps
+
+    monkeypatch.setattr(decode_ops, "interpret_mode", lambda: False)
+    cfg = ep_config()
+    slots, max_seq = 16, 9216
+    on = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+    params = jax.tree.map(on, jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.key(0))[0]))
+    caches = jax.tree.map(on, jax.eval_shape(
+        lambda: T.init_caches(cfg, slots, max_seq)))
+    toks = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    c = lm_steps(cfg, max_seq).decode.lower(
+        params, toks, valid, caches).compile()
+    text = c.as_text()
+    assert _has_kernel(c)
+    assert "bf16[16,1024,8,128]" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 0.1e9
